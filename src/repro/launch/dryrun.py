@@ -99,8 +99,10 @@ VARIANTS: Dict[str, Dict[str, Any]] = {
 
 def run_cell(arch: str, shape_name: str, multi_pod: bool,
              verbose: bool = True, f16_shadow: bool = False,
-             variant: Optional[str] = None) -> Dict[str, Any]:
-    """Lower + compile one (arch x shape x mesh) cell; return its record."""
+             variant: Optional[str] = None,
+             hlo_dir: Optional[str] = None) -> Dict[str, Any]:
+    """Lower + compile one (arch x shape x mesh) cell; return its record.
+    With ``hlo_dir`` the compiled HLO is kept there for ``--reterm``."""
     cfg = get_config(arch)
     shape = shape_by_name(shape_name)
     mesh_name = "pod2" if multi_pod else "pod1"
@@ -202,9 +204,9 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
 
     mem = _mem_report(compiled)
     terms = extract_terms(compiled, n_chips, mf)
-    if not f16_shadow:
-        _save_hlo(arch, shape_name, mesh_name, variant, compiled.as_text(),
-                  n_chips, mf)
+    if hlo_dir is not None:
+        _save_hlo(hlo_dir, arch, shape_name, mesh_name, variant,
+                  compiled.as_text(), n_chips, mf)
     record = {
         "status": "ok",
         "arch": arch, "shape": shape_name, "mesh": mesh_name,
@@ -239,26 +241,29 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     return record
 
 
-HLO_DIR = os.path.join(os.path.dirname(RESULTS_PATH), "hlo")
+def _hlo_dir(results_path: str) -> str:
+    """Cached HLO lives in ``hlo/`` beside the results file."""
+    return os.path.join(os.path.dirname(os.path.abspath(results_path)), "hlo")
 
 
-def _hlo_path(key: str) -> str:
-    return os.path.join(os.path.abspath(HLO_DIR),
+def _hlo_path(hlo_dir: str, key: str) -> str:
+    return os.path.join(hlo_dir,
                         key.replace("|", "__").replace("#", "--") + ".hlo.gz")
 
 
-def _save_hlo(arch, shape_name, mesh_name, variant, text, n_chips, mf):
+def _save_hlo(hlo_dir, arch, shape_name, mesh_name, variant, text, n_chips,
+              mf):
     import gzip
     key = f"{arch}|{shape_name}|{mesh_name}" + (f"#{variant}" if variant
                                                 else "")
-    path = _hlo_path(key)
+    path = _hlo_path(hlo_dir, key)
     os.makedirs(os.path.dirname(path), exist_ok=True)
     with gzip.open(path, "wt") as f:
         f.write(f"# n_chips={n_chips} model_flops={mf}\n")
         f.write(text)
 
 
-def reterm(results: Dict[str, Any]) -> int:
+def reterm(results: Dict[str, Any], hlo_dir: str) -> int:
     """Recompute roofline terms from cached HLO (no recompilation)."""
     import gzip
     from .roofline import RooflineTerms
@@ -267,7 +272,7 @@ def reterm(results: Dict[str, Any]) -> int:
     for key, rec in results.items():
         if rec.get("status") != "ok":
             continue
-        path = _hlo_path(key)
+        path = _hlo_path(hlo_dir, key)
         if not os.path.exists(path):
             continue
         with gzip.open(path, "rt") as f:
@@ -326,8 +331,9 @@ def main() -> None:
               "both": [False, True]}[args.mesh]
 
     results = load_results(args.out)
+    hlo_dir = _hlo_dir(args.out)
     if args.reterm:
-        n = reterm(results)
+        n = reterm(results, hlo_dir)
         save_results(args.out, results)
         print(f"re-derived terms for {n} cells from cached HLO")
         return
@@ -343,7 +349,8 @@ def main() -> None:
                     continue
                 try:
                     results[key] = run_cell(arch, shape_name, multi_pod,
-                                            variant=args.variant)
+                                            variant=args.variant,
+                                            hlo_dir=hlo_dir)
                 except Exception as e:
                     failures += 1
                     results[key] = {"status": "error",

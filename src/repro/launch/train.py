@@ -18,6 +18,7 @@ from ..training import (AdamWConfig, CheckpointManager, Prefetcher,
                         SyntheticDataset, adamw_init, make_train_step)
 from ..training.train_step import settings_for
 from ..distributed.fault_tolerance import TrainSupervisor
+from .compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -34,6 +35,7 @@ def main() -> None:
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = Model(cfg)
     settings = settings_for(args.arch)

@@ -58,8 +58,9 @@ class InferenceEngine:
 
         self._lock = threading.Lock()
         self._pending: Deque[_Request] = deque()
-        self._active: Dict[int, _Request] = {}
+        self._active: Dict[int, _Request] = {}   # slot -> request holding it
         self._free = list(range(scfg.max_batch))
+        self._error: Optional[BaseException] = None  # set once the driver died
         # engine-wide decode state (padded to max_batch)
         self.cache = model.init_cache(scfg.max_batch, scfg.max_len)
         self.steps = 0
@@ -71,7 +72,8 @@ class InferenceEngine:
         self._insert = jax.jit(self._insert_impl)
 
     # ------------------------------------------------------------ plumbing
-    def _insert_impl(self, cache: Any, pcache: Any, slot: jax.Array) -> Any:
+    @staticmethod
+    def _insert_impl(cache: Any, pcache: Any, slot: jax.Array) -> Any:
         """Copy a prefill cache (batch=1) into one slot of the engine cache.
 
         Leaves are (L, B, ...) with the prefill leaf (L, 1, ...); when the
@@ -91,8 +93,23 @@ class InferenceEngine:
         req = _Request(prompt=np.asarray(prompt, np.int32), done=Future(),
                        max_new=max_new or self.scfg.max_new_tokens)
         with self._lock:
-            self._pending.append(req)
+            error = self._error
+            if error is None:
+                self._pending.append(req)
+        if error is not None:
+            req.done.set_exception(error)
         return req.done
+
+    def fail_all(self, exc: BaseException) -> None:
+        """The driver died with ``exc``: fail every pending and admitted
+        request with it, and every request submitted from now on."""
+        with self._lock:
+            self._error = exc
+            reqs = list(self._pending) + list(self._active.values())
+            self._pending.clear()
+            self._active.clear()
+        for req in reqs:
+            req.done.set_exception(exc)
 
     # ------------------------------------------------------- engine phases
     def admit_one(self) -> Optional[Tuple[Any, ...]]:
@@ -102,6 +119,7 @@ class InferenceEngine:
                 return None
             req = self._pending.popleft()
             req.slot = self._free.pop()
+            self._active[req.slot] = req
         return (req,)
 
     def do_prefill(self, req: _Request) -> None:
@@ -114,16 +132,15 @@ class InferenceEngine:
         self.cache = self._insert(self.cache, pcache,
                                   jnp.asarray(req.slot, jnp.int32))
         tok = int(np.argmax(np.asarray(logits)[0]))
-        req.tokens.append(tok)
-        req.pos = P                      # next insert position
-        with self._lock:
-            self._active[req.slot] = req
+        with self._lock:                 # the slot joins the next decode step
+            req.pos = P                  # next insert position
+            req.tokens.append(tok)
 
     def do_decode_step(self) -> List[_Request]:
         """One continuous-batching decode step (offload-pool work).
         Returns requests that finished this step."""
-        with self._lock:
-            active = dict(self._active)
+        with self._lock:                 # slots whose prefill has finished
+            active = {s: r for s, r in self._active.items() if r.tokens}
         if not active:
             return []
         B = self.scfg.max_batch

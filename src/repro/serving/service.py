@@ -24,13 +24,16 @@ from .engine import InferenceEngine, ServeConfig
 IDLE_SLEEP = 0.002
 
 
-def _tokenize(svc: Any, payload: Any):
-    """Toy tokenizer service: bytes -> token ids (real CPU work)."""
-    yield Compute(5e-6)
-    text = payload["text"]
+def tokenize(text: str, vocab_size: int) -> np.ndarray:
+    """Toy tokenizer: one token id per UTF-8 byte."""
     ids = np.frombuffer(text.encode("utf-8"), dtype=np.uint8).astype(np.int32)
-    vocab = svc.state["vocab_size"]
-    return {"ids": ids % vocab}
+    return ids % vocab_size
+
+
+def _tokenize(svc: Any, payload: Any):
+    """Tokenizer service (real CPU work)."""
+    yield Compute(5e-6)
+    return {"ids": tokenize(payload["text"], svc.state["vocab_size"])}
 
 
 def _detokenize(svc: Any, payload: Any):
@@ -61,20 +64,26 @@ def _submit(svc: Any, payload: Any):
 
 
 def _run(svc: Any, payload: Any):
-    """The engine driver: a single long-lived fiber."""
+    """The engine driver: a single long-lived fiber.  If device work raises
+    (a compile or out-of-memory error), every request in the engine fails
+    with that error at once instead of waiting out its client's timeout."""
     engine: InferenceEngine = svc.state["engine"]
-    while not svc.state.get("stop"):
-        progressed = False
-        admitted = engine.admit_one()
-        if admitted is not None:
-            req = admitted[0]
-            yield Wait((yield Offload(engine.do_prefill, (req,))))
-            progressed = True
-        finished = yield Wait((yield Offload(engine.do_decode_step)))
-        if finished:
-            progressed = True
-        if not progressed and not engine.has_work():
-            yield Sleep(IDLE_SLEEP)
+    try:
+        while not svc.state.get("stop"):
+            progressed = False
+            admitted = engine.admit_one()
+            if admitted is not None:
+                req = admitted[0]
+                yield Wait((yield Offload(engine.do_prefill, (req,))))
+                progressed = True
+            finished = yield Wait((yield Offload(engine.do_decode_step)))
+            if finished:
+                progressed = True
+            if not progressed and not engine.has_work():
+                yield Sleep(IDLE_SLEEP)
+    except Exception as exc:
+        engine.fail_all(exc)
+        raise
     return "stopped"
 
 

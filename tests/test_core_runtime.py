@@ -1,21 +1,11 @@
-"""Unit + property tests for the async-RPC substrate (threads vs fibers).
-
-The property tests use ``hypothesis`` when it is installed; a deterministic
-seeded fallback covers the same invariants otherwise, so the module always
-collects (the suite must not die on an optional dev dependency).
-"""
+"""Unit + property tests for the async-RPC substrate (threads vs fibers)."""
 import threading
 import time
 
 import numpy as np
 import pytest
-
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-    HAVE_HYPOTHESIS = True
-except ImportError:  # pragma: no cover - exercised on minimal installs
-    HAVE_HYPOTHESIS = False
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import (App, AsyncRpc, Compute, Future, ServiceSpec, Sleep,
                         SpawnLocal, Wait, WaitAll, sync_rpc)
@@ -209,32 +199,19 @@ def _check_fanout_sum(n, backend):
         assert app.send("fan", "fanout", {"n": n}).wait(timeout=10) == n * (n - 1) // 2
 
 
-if HAVE_HYPOTHESIS:
-    @settings(max_examples=10, deadline=None)
-    @given(st.lists(st.integers(min_value=0, max_value=1000), min_size=1,
-                    max_size=40),
-           st.sampled_from(BACKENDS))
-    def test_property_all_requests_complete_correctly(values, backend):
-        _check_all_requests_complete_correctly(values, backend)
+@settings(max_examples=10, deadline=None)
+@given(st.lists(st.integers(min_value=0, max_value=1000), min_size=1,
+                max_size=40),
+       st.sampled_from(BACKENDS))
+def test_property_all_requests_complete_correctly(values, backend):
+    _check_all_requests_complete_correctly(values, backend)
 
-    @settings(max_examples=6, deadline=None)
-    @given(st.integers(min_value=1, max_value=30),
-           st.sampled_from(BACKENDS))
-    def test_property_fanout_sum(n, backend):
-        _check_fanout_sum(n, backend)
-else:
-    @pytest.mark.parametrize("backend", BACKENDS)
-    def test_property_all_requests_complete_correctly_fallback(backend):
-        rng = np.random.default_rng(0)
-        for _ in range(4):
-            size = int(rng.integers(1, 41))
-            values = rng.integers(0, 1001, size=size).tolist()
-            _check_all_requests_complete_correctly(values, backend)
 
-    @pytest.mark.parametrize("backend", BACKENDS)
-    def test_property_fanout_sum_fallback(backend):
-        for n in (1, 2, 7, 30):
-            _check_fanout_sum(n, backend)
+@settings(max_examples=6, deadline=None)
+@given(st.integers(min_value=1, max_value=30),
+       st.sampled_from(BACKENDS))
+def test_property_fanout_sum(n, backend):
+    _check_fanout_sum(n, backend)
 
 
 # ----------------------------------------------------- fiber scheduler unit

@@ -10,20 +10,8 @@ import textwrap
 import time
 
 import numpy as np
-import pytest
 
 from repro.distributed.fault_tolerance import HeartbeatMonitor
-
-jax = pytest.importorskip("jax")
-
-# The mesh-building helpers (repro.launch.mesh / repro.distributed.sharding)
-# require jax.sharding.AxisType, which this environment's jax predates —
-# version drift tracked in CHANGES.md.  Guard the mesh-dependent tests so
-# tier-1 stays signal on either jax version.
-needs_axistype = pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="jax version drift: jax.sharding.AxisType unavailable "
-           "(pre-existing, tracked in CHANGES.md)")
 
 
 def _run_subprocess(code: str) -> str:
@@ -40,7 +28,6 @@ def _run_subprocess(code: str) -> str:
     return out.stdout
 
 
-@needs_axistype
 def test_sharding_rules_divisibility_degrade():
     out = _run_subprocess("""
     import jax, json
@@ -74,7 +61,6 @@ def test_sharding_rules_divisibility_degrade():
     assert res["grok_wgate"].startswith("PartitionSpec(None, 'model'")
 
 
-@needs_axistype
 def test_grok_expert_fallback_at_tp16():
     """At TP=8 (> n_experts would not divide), grok-1's 8 experts divide 8,
     but with mesh model=3 they cannot -> TP inside experts instead."""
@@ -101,7 +87,6 @@ def test_grok_expert_fallback_at_tp16():
     assert res["wgate"] == "PartitionSpec(None, None, 'data', 'model')"
 
 
-@needs_axistype
 def test_compressed_cross_pod_reduction():
     out = _run_subprocess("""
     import jax, jax.numpy as jnp, numpy as np, json
@@ -136,7 +121,6 @@ def test_compressed_cross_pod_reduction():
     assert res["rel_err"] < 0.02       # int8 quantization noise only
 
 
-@needs_axistype
 def test_elastic_reshard_across_meshes():
     out = _run_subprocess("""
     import jax, jax.numpy as jnp, numpy as np, json

@@ -128,3 +128,35 @@ def test_engine_ssm_family():
     while not done.done:
         eng.do_decode_step()
     assert len(done.wait(timeout=5)) == 3
+
+
+class _DeviceFault(RuntimeError):
+    """Stands in for an error the device raises (compile, out of memory)."""
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_device_error_reaches_the_client(backend):
+    """A device error in the engine fails every request with that error
+    within seconds (not the client's timeout), and later ones at once."""
+    model, params = _tiny_model()
+    scfg = ServeConfig(max_batch=2, max_len=64, prefill_bucket=16,
+                       max_new_tokens=4)
+    app = build_llm_app(model, params, scfg, backend=backend)
+
+    def broken_prefill(req):
+        raise _DeviceFault("prefill failed on the device")
+
+    app.state["engine"].do_prefill = broken_prefill
+    with app:
+        run = app.send("engine", "run", None)
+        t0 = time.perf_counter()
+        futs = [app.send("api", "generate", {"text": f"req {i}"})
+                for i in range(3)]          # more requests than slots
+        for fut in futs:
+            with pytest.raises(_DeviceFault):
+                fut.wait(timeout=10)
+        assert time.perf_counter() - t0 < 5
+        with pytest.raises(_DeviceFault):
+            run.wait(timeout=10)
+        with pytest.raises(_DeviceFault):
+            app.send("api", "generate", {"text": "later"}).wait(timeout=10)
