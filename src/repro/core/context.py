@@ -21,12 +21,18 @@ import itertools
 import zlib
 from typing import Optional, Union
 
-__all__ = ["RequestContext", "session_key"]
+__all__ = ["RequestContext", "session_key", "trace_ticket"]
 
 SessionId = Union[str, bytes, int, None]
 
 #: Process-wide trace ticket source (atomic under the GIL).
 _trace_ticket = itertools.count(1)
+
+
+def trace_ticket() -> int:
+    """A fresh trace id from the source every :class:`RequestContext`
+    draws from, for a request that arrived without a context."""
+    return next(_trace_ticket)
 
 
 def session_key(session: SessionId) -> int:

@@ -64,6 +64,8 @@ class Hooks:
         :meth:`recorder_summary`
     anyone (self-tests, lock proxies)
         :meth:`lock_acquire`, :meth:`lock_release`, :meth:`access`
+    ``repro.serving`` (the LLM server's request path and engine loop)
+        :meth:`span_begin`, :meth:`span_end`, :meth:`event`
     """
 
     # ------------------------------------------------------------- futures
@@ -154,6 +156,20 @@ class Hooks:
 
     def access(self, key: Any, write: bool) -> None:
         """The current thread touched shared state ``key`` (race check)."""
+
+    # ------------------------------------------------------------- spans
+    def span_begin(self, name: str, rid: Optional[int]) -> None:
+        """The span ``name`` of request ``rid`` opened (``rid`` None: the
+        engine loop's own work, or the host's)."""
+
+    def span_end(self, name: str, rid: Optional[int],
+                 attrs: Optional[dict]) -> None:
+        """The open span ``name`` of ``rid`` closed; ``attrs`` are numbers
+        known only at its end (or None)."""
+
+    def event(self, name: str, rid: Optional[int],
+              attrs: Optional[dict]) -> None:
+        """An instant ``name`` in request ``rid``."""
 
 
 def install(h: Hooks) -> None:

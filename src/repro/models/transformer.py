@@ -34,23 +34,25 @@ def block_fwd(x: jax.Array, p: Dict[str, Any], cfg: ModelConfig,
         # pin first (anchors the remat-saved carry's sharding), then gather
         x = shard_hint(x, "batch", "carry_seq", None)
         x = shard_hint(x, "batch", None, None)    # gather for the interior
-    h = rms_norm(x, p["ln1"], cfg.norm_eps)
-    if cfg.use_mla:
-        qq = mla_project_q(h, p["attn"], cfg, positions)
-        latent, k_rope = mla_latent(h, p["attn"], cfg, positions)
-        attn = mla_attend(qq, latent, k_rope, mask, p["attn"], cfg)
-        kv = (latent, k_rope)
-    else:
-        q, k, v = gqa_project(h, p["attn"], cfg, positions)
-        attn = gqa_attend(q, k, v, mask, p["attn"], cfg)
-        kv = (k, v)
-    x = x + attn
-    h = rms_norm(x, p["ln2"], cfg.norm_eps)
-    if cfg.n_experts:
-        ff, aux = moe_ffn(h, p["mlp"], cfg)
-    else:
-        ff, aux = swiglu_mlp(h, p["mlp"]), jnp.zeros((), jnp.float32)
-    out = x + ff
+    with jax.named_scope("attention"):
+        h = rms_norm(x, p["ln1"], cfg.norm_eps)
+        if cfg.use_mla:
+            qq = mla_project_q(h, p["attn"], cfg, positions)
+            latent, k_rope = mla_latent(h, p["attn"], cfg, positions)
+            attn = mla_attend(qq, latent, k_rope, mask, p["attn"], cfg)
+            kv = (latent, k_rope)
+        else:
+            q, k, v = gqa_project(h, p["attn"], cfg, positions)
+            attn = gqa_attend(q, k, v, mask, p["attn"], cfg)
+            kv = (k, v)
+        x = x + attn
+    with jax.named_scope("mlp"):
+        h = rms_norm(x, p["ln2"], cfg.norm_eps)
+        if cfg.n_experts:
+            ff, aux = moe_ffn(h, p["mlp"], cfg)
+        else:
+            ff, aux = swiglu_mlp(h, p["mlp"]), jnp.zeros((), jnp.float32)
+        out = x + ff
     if out.shape[1] > 1:
         out = shard_hint(out, "batch", "carry_seq", None)  # boundary carry
     return out, kv, aux
@@ -65,31 +67,36 @@ def block_decode(x: jax.Array, p: Dict[str, Any], cfg: ModelConfig,
     """
     B = x.shape[0]
     positions = pos[:, None]                                    # (B,1)
-    h = rms_norm(x, p["ln1"], cfg.norm_eps)
-    if cfg.use_mla:
-        lat_new, rope_new = mla_latent(h, p["attn"], cfg, positions)
-        lat, ropek = update_mla_cache(cache_l["latent"], cache_l["k_rope"],
-                                      lat_new, rope_new, pos)
-        mask = _cache_mask(pos, lat.shape[1])
-        qq = mla_project_q(h, p["attn"], cfg, positions)
-        attn = mla_attend(qq, lat, ropek, mask, p["attn"], cfg)
-        new_cache = {"latent": lat, "k_rope": ropek}
-    else:
-        q, k_new, v_new = gqa_project(h, p["attn"], cfg, positions)
-        ck, cv = update_full_cache(cache_l["k"], cache_l["v"],
-                                   k_new, v_new, pos)
-        mask = _cache_mask(pos, ck.shape[1])
-        ck_a = shard_hint(ck, "batch", "kv_seq", None, None)
-        cv_a = shard_hint(cv, "batch", "kv_seq", None, None)
-        attn = gqa_attend(q, ck_a, cv_a, mask, p["attn"], cfg)
-        new_cache = {"k": ck, "v": cv}
-    x = x + attn
-    h = rms_norm(x, p["ln2"], cfg.norm_eps)
-    if cfg.n_experts:
-        ff, _ = moe_ffn(h, p["mlp"], cfg)
-    else:
-        ff = swiglu_mlp(h, p["mlp"])
-    return x + ff, new_cache
+    with jax.named_scope("attention"):
+        h = rms_norm(x, p["ln1"], cfg.norm_eps)
+        if cfg.use_mla:
+            lat_new, rope_new = mla_latent(h, p["attn"], cfg, positions)
+            with jax.named_scope("cache_update"):
+                lat, ropek = update_mla_cache(cache_l["latent"],
+                                              cache_l["k_rope"], lat_new,
+                                              rope_new, pos)
+            mask = _cache_mask(pos, lat.shape[1])
+            qq = mla_project_q(h, p["attn"], cfg, positions)
+            attn = mla_attend(qq, lat, ropek, mask, p["attn"], cfg)
+            new_cache = {"latent": lat, "k_rope": ropek}
+        else:
+            q, k_new, v_new = gqa_project(h, p["attn"], cfg, positions)
+            with jax.named_scope("cache_update"):
+                ck, cv = update_full_cache(cache_l["k"], cache_l["v"],
+                                           k_new, v_new, pos)
+            mask = _cache_mask(pos, ck.shape[1])
+            ck_a = shard_hint(ck, "batch", "kv_seq", None, None)
+            cv_a = shard_hint(cv, "batch", "kv_seq", None, None)
+            attn = gqa_attend(q, ck_a, cv_a, mask, p["attn"], cfg)
+            new_cache = {"k": ck, "v": cv}
+        x = x + attn
+    with jax.named_scope("mlp"):
+        h = rms_norm(x, p["ln2"], cfg.norm_eps)
+        if cfg.n_experts:
+            ff, _ = moe_ffn(h, p["mlp"], cfg)
+        else:
+            ff = swiglu_mlp(h, p["mlp"])
+        return x + ff, new_cache
 
 
 def _cache_mask(pos: jax.Array, max_len: int) -> jax.Array:
@@ -156,8 +163,10 @@ def prefill(params: Dict[str, Any], cfg: ModelConfig,
 
     body_fn = jax.checkpoint(body) if cfg.remat else body
     x, kvs = jax.lax.scan(body_fn, x, params["blocks"])
-    x = rms_norm(x, params["ln_f"], cfg.norm_eps)
-    logits = lm_logits(x[:, -1:], _out_table(params, cfg), cfg.logit_softcap)
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["ln_f"], cfg.norm_eps)
+        logits = lm_logits(x[:, -1:], _out_table(params, cfg),
+                           cfg.logit_softcap)
     if cfg.use_mla:
         cache = {"latent": kvs[0], "k_rope": kvs[1]}
     else:
@@ -200,8 +209,9 @@ def decode_step(params: Dict[str, Any], cfg: ModelConfig,
             return h2, new_cache_l
 
         x, new_cache = jax.lax.scan(body, x, (params["blocks"], cache))
-    x = rms_norm(x, params["ln_f"], cfg.norm_eps)
-    logits = lm_logits(x, _out_table(params, cfg), cfg.logit_softcap)
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["ln_f"], cfg.norm_eps)
+        logits = lm_logits(x, _out_table(params, cfg), cfg.logit_softcap)
     return logits[:, 0], new_cache
 
 

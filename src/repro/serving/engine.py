@@ -9,18 +9,30 @@ kernel thread under the paper's baseline backend); device work goes through
 The engine supports the decoder-LM families (dense / moe / vlm-text); the
 recurrent families serve through the same Model API but keep O(1) state, so
 slot caches are trivially small.
+
+Spans (``repro.core.instrument``; recorded by ``spans.SpanRecorder``): per
+request ``engine.request`` (submit to done) with its child
+``engine.queued`` (submit to admission) and the instant
+``engine.first_token``; per prefill ``engine.prefill`` with
+``prefill.dispatch``, ``prefill.fetch`` and ``prefill.sample``; per decode
+step ``engine.decode_step`` with ``decode.prepare``, ``decode.dispatch``,
+``decode.device_wait``, ``decode.fetch``, ``decode.sample`` and
+``decode.commit``, and ``engine.lock_wait`` for each wait on the lock.
 """
 from __future__ import annotations
 
 import threading
 from collections import deque
 from dataclasses import dataclass, field
+from functools import partial
 from typing import Any, Deque, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core import instrument
+from ..core.context import trace_ticket
 from ..core.future import Future
 from ..models import Model
 
@@ -43,6 +55,12 @@ class _Request:
     tokens: List[int] = field(default_factory=list)
     slot: int = -1
     pos: int = 0                  # next absolute position to write
+    rid: Optional[int] = None     # trace id of its spans (traced runs)
+
+
+def _end_request(h: instrument.Hooks, rid: int, _done: Future) -> None:
+    h.span_end("engine.queued", rid, None)    # open if it failed unadmitted
+    h.span_end("engine.request", rid, None)
 
 
 class InferenceEngine:
@@ -86,12 +104,21 @@ class InferenceEngine:
                 row = jax.lax.dynamic_update_slice_in_dim(
                     big[:, slot], row, 0, axis=1)
             return jax.lax.dynamic_update_index_in_dim(big, row, slot, axis=1)
-        return jax.tree.map(ins, cache, pcache)
+        with jax.named_scope("cache_update"):
+            return jax.tree.map(ins, cache, pcache)
 
-    def submit(self, prompt: np.ndarray,
-               max_new: Optional[int] = None) -> Future:
+    def submit(self, prompt: np.ndarray, max_new: Optional[int] = None,
+               rid: Optional[int] = None) -> Future:
+        """Queue a request; ``rid`` labels its spans (a fresh trace id when
+        spans are recorded and none is given)."""
         req = _Request(prompt=np.asarray(prompt, np.int32), done=Future(),
                        max_new=max_new or self.scfg.max_new_tokens)
+        h = instrument.hooks
+        if h is not None:
+            req.rid = trace_ticket() if rid is None else rid
+            h.span_begin("engine.request", req.rid)
+            h.span_begin("engine.queued", req.rid)
+            req.done.add_done_callback(partial(_end_request, h, req.rid))
         with self._lock:
             error = self._error
             if error is None:
@@ -120,10 +147,17 @@ class InferenceEngine:
             req = self._pending.popleft()
             req.slot = self._free.pop()
             self._active[req.slot] = req
+        h = instrument.hooks
+        if h is not None:
+            h.span_end("engine.queued", req.rid, None)
         return (req,)
 
     def do_prefill(self, req: _Request) -> None:
         """Blocking device work — runs on the offload pool."""
+        h = instrument.hooks
+        if h is not None:
+            h.span_begin("engine.prefill", None)
+            h.span_begin("prefill.dispatch", None)
         P = self.scfg.prefill_bucket
         n = min(len(req.prompt), P)
         padded = np.zeros((1, P), np.int32)
@@ -131,17 +165,40 @@ class InferenceEngine:
         logits, pcache = self._prefill(self.params, {"tokens": padded})
         self.cache = self._insert(self.cache, pcache,
                                   jnp.asarray(req.slot, jnp.int32))
-        tok = int(np.argmax(np.asarray(logits)[0]))
+        if h is not None:
+            h.span_end("prefill.dispatch", None, None)
+            h.span_begin("prefill.fetch", None)
+        logits = np.asarray(logits)[0]
+        if h is not None:
+            h.span_end("prefill.fetch", None, None)
+            h.span_begin("prefill.sample", None)
+        tok = int(np.argmax(logits))
+        if h is not None:
+            h.span_end("prefill.sample", None, None)
         with self._lock:                 # the slot joins the next decode step
             req.pos = P                  # next insert position
             req.tokens.append(tok)
+        if h is not None:
+            h.event("engine.first_token", req.rid, None)
+            h.span_end("engine.prefill", None, None)
 
     def do_decode_step(self) -> List[_Request]:
         """One continuous-batching decode step (offload-pool work).
         Returns requests that finished this step."""
+        h = instrument.hooks
+        if h is not None:
+            h.span_begin("engine.decode_step", None)
+            h.span_begin("decode.prepare", None)
+            h.span_begin("engine.lock_wait", None)
         with self._lock:                 # slots whose prefill has finished
+            if h is not None:
+                h.span_end("engine.lock_wait", None, None)
             active = {s: r for s, r in self._active.items() if r.tokens}
+            pending = len(self._pending)
         if not active:
+            if h is not None:
+                h.span_end("decode.prepare", None, None)
+                h.span_end("engine.decode_step", None, {"active": 0})
             return []
         B = self.scfg.max_batch
         toks = np.zeros((B, 1), np.int32)
@@ -149,15 +206,35 @@ class InferenceEngine:
         for slot, req in active.items():
             toks[slot, 0] = req.tokens[-1]
             pos[slot] = req.pos
+        if h is not None:
+            h.span_end("decode.prepare", None, None)
+            h.span_begin("decode.dispatch", None)
         logits, self.cache = self._decode(self.params, self.cache,
                                           jnp.asarray(toks),
                                           jnp.asarray(pos))
+        if h is not None:
+            h.span_end("decode.dispatch", None, None)
+            h.span_begin("decode.device_wait", None)
+        logits.block_until_ready()
+        if h is not None:
+            h.span_end("decode.device_wait", None, None)
+            h.span_begin("decode.fetch", None)
         logits = np.asarray(logits)
+        if h is not None:
+            h.span_end("decode.fetch", None, None)
+            h.span_begin("decode.sample", None)
+        picks = {slot: int(np.argmax(logits[slot])) for slot in active}
         self.steps += 1
+        if h is not None:
+            h.span_end("decode.sample", None, None)
+            h.span_begin("decode.commit", None)
+            h.span_begin("engine.lock_wait", None)
         finished = []
         with self._lock:
+            if h is not None:
+                h.span_end("engine.lock_wait", None, None)
             for slot, req in active.items():
-                tok = int(np.argmax(logits[slot]))
+                tok = picks[slot]
                 req.tokens.append(tok)
                 req.pos += 1
                 self.generated += 1
@@ -170,7 +247,24 @@ class InferenceEngine:
                     self._free.append(req.slot)
         for req in finished:          # resolve outside the lock
             req.done.set_result(req.tokens)
+        if h is not None:
+            h.span_end("decode.commit", None, None)
+            h.span_end("engine.decode_step", None,
+                       self._step_attrs(active, pending))
         return finished
+
+    def _step_attrs(self, active: Dict[int, _Request],
+                    pending: int) -> dict:
+        """The numbers of the step just taken: its index, the slots it
+        decoded, their context (cache entries attended, padding included),
+        the entries that hold real tokens (the prompt really sent, and the
+        tokens generated), and the requests still queued."""
+        P = self.scfg.prefill_bucket
+        ctx = sum(r.pos for r in active.values())   # pos already advanced
+        real = sum(min(len(r.prompt), P) + len(r.tokens) - 1
+                   for r in active.values())
+        return {"step": self.steps, "active": len(active), "ctx": ctx,
+                "real": real, "pending": pending}
 
     def has_work(self) -> bool:
         with self._lock:
