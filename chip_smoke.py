@@ -77,8 +77,9 @@ def greedy_reference(engine: InferenceEngine, ids: np.ndarray, n_new: int
 
     Runs the engine's own jitted prefill and decode_step (the very programs
     the server ran), with the padded prompt placed by plain indexing in row 0
-    of an otherwise idle batch, so equality with the served tokens checks
-    the engine's slot insertion and batching, not the compiler."""
+    of an otherwise idle batch and the argmax taken on the host, so equality
+    with the served tokens checks the engine's slot insertion, batching and
+    greedy pick on the device, not the compiler."""
     scfg = engine.scfg
     P, B = scfg.prefill_bucket, scfg.max_batch
     padded = np.zeros((1, P), np.int32)

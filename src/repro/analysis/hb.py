@@ -4,7 +4,7 @@ The model is the classic vector-clock data-race detector, adapted to the
 runtime's event vocabulary instead of raw memory operations:
 
 * every **thread** (kernel carrier, scheduler loop, timer thread, test
-  thread) owns a vector clock, keyed by ``threading.get_ident()``;
+  thread) owns a vector clock, keyed by the sanitizer's own thread id;
 * a **release edge** on a channel (mailbox put, ring submit, future set,
   fiber injection) joins the releasing thread's clock into the channel's
   clock, then advances the releaser;

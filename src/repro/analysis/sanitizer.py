@@ -49,6 +49,7 @@ branch per event site.
 from __future__ import annotations
 
 import contextlib
+import itertools
 import threading
 from collections import Counter
 from dataclasses import dataclass, field
@@ -79,6 +80,8 @@ class Sanitizer(instrument.Hooks):
 
     def __init__(self) -> None:
         self._mu = threading.RLock()
+        self._thread = threading.local()
+        self._tids = itertools.count(1)
         self.hb = HBState()
         self.lockgraph = LockOrderGraph()
         self.counts: Counter = Counter()
@@ -105,9 +108,14 @@ class Sanitizer(instrument.Hooks):
         sev = "warn" if rule in _WARN_RULES else "error"
         self.findings.append(Finding(rule, message, sev))
 
-    @staticmethod
-    def _tid() -> int:
-        return threading.get_ident()
+    def _tid(self) -> int:
+        """The calling thread's id, never given to another thread:
+        ``threading.get_ident()`` is reused by a thread started after one
+        ended, which would make their accesses look ordered."""
+        tid = getattr(self._thread, "tid", None)
+        if tid is None:
+            tid = self._thread.tid = next(self._tids)
+        return tid
 
     # ------------------------------------------------------------- futures
     def future_set(self, fut: Any) -> None:

@@ -14,10 +14,17 @@ Spans (``repro.core.instrument``; recorded by ``spans.SpanRecorder``): per
 request ``engine.request`` (submit to done) with its child
 ``engine.queued`` (submit to admission) and the instant
 ``engine.first_token``; per prefill ``engine.prefill`` with
-``prefill.dispatch``, ``prefill.fetch`` and ``prefill.sample``; per decode
-step ``engine.decode_step`` with ``decode.prepare``, ``decode.dispatch``,
-``decode.device_wait``, ``decode.fetch``, ``decode.sample`` and
-``decode.commit``, and ``engine.lock_wait`` for each wait on the lock.
+``prefill.dispatch`` (prefill, insert and pick dispatched),
+``prefill.fetch`` (waits for them and copies one int32 id) and
+``prefill.sample`` (reads it); per decode step ``engine.decode_step`` with
+``decode.prepare``, ``decode.dispatch`` (the decode program and the pick
+dispatched), ``decode.device_wait`` (waits for both), ``decode.fetch``
+(copies B int32 ids), ``decode.sample`` (reads the id of each active slot)
+and ``decode.commit``, and ``engine.lock_wait`` for each wait on the lock.
+
+Greedy tokens are picked on the device by ``_greedy_pick``, a program of
+its own applied to whatever ``_prefill``/``_decode`` return, so the host
+copies one id per slot and not a row of ``vocab`` logits.
 """
 from __future__ import annotations
 
@@ -58,6 +65,11 @@ class _Request:
     rid: Optional[int] = None     # trace id of its spans (traced runs)
 
 
+def _greedy_pick(logits: jax.Array) -> jax.Array:
+    """Each row's largest logit's id, the lowest on ties, as ``np.argmax``."""
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
 def _end_request(h: instrument.Hooks, rid: int, _done: Future) -> None:
     h.span_end("engine.queued", rid, None)    # open if it failed unadmitted
     h.span_end("engine.request", rid, None)
@@ -88,6 +100,7 @@ class InferenceEngine:
         self._prefill = jax.jit(model.prefill)
         self._decode = jax.jit(model.decode_step)
         self._insert = jax.jit(self._insert_impl)
+        self._pick = jax.jit(_greedy_pick)
 
     # ------------------------------------------------------------ plumbing
     @staticmethod
@@ -165,14 +178,15 @@ class InferenceEngine:
         logits, pcache = self._prefill(self.params, {"tokens": padded})
         self.cache = self._insert(self.cache, pcache,
                                   jnp.asarray(req.slot, jnp.int32))
+        ids = self._pick(logits)
         if h is not None:
             h.span_end("prefill.dispatch", None, None)
             h.span_begin("prefill.fetch", None)
-        logits = np.asarray(logits)[0]
+        ids = np.asarray(ids)
         if h is not None:
             h.span_end("prefill.fetch", None, None)
             h.span_begin("prefill.sample", None)
-        tok = int(np.argmax(logits))
+        tok = int(ids[0])
         if h is not None:
             h.span_end("prefill.sample", None, None)
         with self._lock:                 # the slot joins the next decode step
@@ -212,18 +226,19 @@ class InferenceEngine:
         logits, self.cache = self._decode(self.params, self.cache,
                                           jnp.asarray(toks),
                                           jnp.asarray(pos))
+        ids = self._pick(logits)
         if h is not None:
             h.span_end("decode.dispatch", None, None)
             h.span_begin("decode.device_wait", None)
-        logits.block_until_ready()
+        ids.block_until_ready()
         if h is not None:
             h.span_end("decode.device_wait", None, None)
             h.span_begin("decode.fetch", None)
-        logits = np.asarray(logits)
+        ids = np.asarray(ids)
         if h is not None:
             h.span_end("decode.fetch", None, None)
             h.span_begin("decode.sample", None)
-        picks = {slot: int(np.argmax(logits[slot])) for slot in active}
+        picks = {slot: int(ids[slot]) for slot in active}
         self.steps += 1
         if h is not None:
             h.span_end("decode.sample", None, None)

@@ -3,13 +3,15 @@
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs import get_smoke_config
 from repro.models import Model
 from repro.serving import ServeConfig, build_llm_app
-from repro.serving.engine import InferenceEngine
+from repro.serving.engine import InferenceEngine, _greedy_pick
+from test_chip_smoke import chip_smoke
 
 BACKENDS = ("fiber", "thread")
 
@@ -80,6 +82,56 @@ def test_engine_greedy_matches_sequential_decode():
         toks.append(int(np.argmax(np.asarray(lg)[0])))
         pos += 1
     assert engine_tokens == toks
+
+
+@pytest.mark.parametrize("rows", [1, 5])
+def test_greedy_pick_matches_np_argmax(rows):
+    """The device pick is ``np.argmax`` per row: ties go to the lowest id,
+    ``-inf`` entries are never picked unless the whole row is ``-inf``."""
+    V = 37
+    rng = np.random.default_rng(rows)
+    logits = rng.standard_normal((rows, V)).astype(np.float32)
+    logits[0, [4, 9, 30]] = 7.0                    # a three-way tie
+    logits[0, [0, 1, 2]] = -np.inf
+    if rows > 1:
+        logits[1, :] = -np.inf                     # nothing but -inf
+        logits[2, :] = 0.5                         # every id ties
+        logits[3, [V - 1, 11]] = 9.0               # tie, out of order
+        logits[4, ::2] = -np.inf
+    picks = jax.jit(_greedy_pick)(jnp.asarray(logits))
+    assert picks.dtype == jnp.int32 and picks.shape == (rows,)
+    np.testing.assert_array_equal(np.asarray(picks), np.argmax(logits, -1))
+    assert int(picks[0]) == 4
+
+
+@pytest.mark.parametrize("arch", ["qwen2-0.5b", "minicpm3-4b"])
+def test_served_tokens_equal_host_argmax_of_decode_logits(arch):
+    """Tokens picked on the device equal a host argmax over ``_decode``'s
+    logits (``chip_smoke.greedy_reference``: each request alone in slot 0),
+    with two of four slots active and, once the shorter request ends, three
+    slots padded."""
+    model, params = _tiny_model(arch)
+    # At init the blocks barely move the residual, and qwen2's tied head
+    # then echoes the last (pad) token; larger weights give each request
+    # tokens of its own, so a pick read from the wrong slot shows.
+    params = {**params, "blocks": jax.tree.map(
+        lambda w: w * 8 if w.ndim == 3 else w, params["blocks"])}
+    scfg = ServeConfig(max_batch=4, max_len=64, prefill_bucket=16,
+                       max_new_tokens=6)
+    V = model.cfg.vocab_size
+    prompts = [(np.arange(9, dtype=np.int32) * 5 + 1) % V,
+               (np.arange(13, dtype=np.int32) * 11 + 2) % V]
+    lengths = [6, 3]
+    eng = InferenceEngine(model, params, scfg)
+    dones = [eng.submit(p, max_new=n) for p, n in zip(prompts, lengths)]
+    for _ in prompts:
+        eng.do_prefill(eng.admit_one()[0])
+    while not all(d.done for d in dones):
+        eng.do_decode_step()
+    served = [d.wait(timeout=5) for d in dones]
+    assert served[0][:3] != served[1]
+    assert served == [chip_smoke.greedy_reference(eng, p, n)[0]
+                      for p, n in zip(prompts, lengths)]
 
 
 @pytest.mark.parametrize("backend", BACKENDS)

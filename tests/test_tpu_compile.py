@@ -20,6 +20,7 @@ from repro.kernels.rglru_scan.kernel import rglru_scan_fwd
 from repro.kernels.rwkv6_scan.kernel import wkv6_fwd
 from repro.models import Model
 from repro.serving import InferenceEngine
+from repro.serving.engine import _greedy_pick
 from test_chip_smoke import chip_smoke
 
 HBM_BYTES = 16 * 2**30          # one v5e chip
@@ -117,11 +118,13 @@ def _qwen_step(kind, model, params, cache):
     if kind == "decode_step":
         return model.decode_step, (params, cache, sd((_B, 1), i32),
                                    sd((_B,), i32))
+    if kind == "pick":
+        return _greedy_pick, (sd((_B, model.cfg.vocab_size), f32),)
     pcache = jax.eval_shape(model.prefill, params, tokens)[1]
     return InferenceEngine._insert_impl, (cache, pcache, sd((), i32))
 
 
-@pytest.mark.parametrize("kind", ("prefill", "decode_step", "insert"))
+@pytest.mark.parametrize("kind", ("prefill", "decode_step", "insert", "pick"))
 def test_qwen2_served_step_compiles_for_v5e(kind, qwen, one_chip):
     fn, args = _qwen_step(kind, *qwen)
     mem = _compile(fn, args, one_chip).memory_analysis()
