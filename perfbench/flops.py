@@ -1,6 +1,16 @@
 """Operations and bytes the served algorithm needs, from a configuration's
 published sizes.  Independent of how the program implements them.
 
+Each reference module (``references/<reference>.py``) counts its own kind
+through ``counts(c)``; ``counts`` here finds the module by the
+configuration's ``reference`` key.  The metric readers call only three
+methods of what it returns: ``prefill_flops(n)``, ``decode_flops(active,
+ctx)`` and ``decode_bytes(active, ctx)``.  A module whose step does not read
+every weight once (an expert layer reads the experts its tokens pick) may
+return its own subclass of ``Counts`` that overrides them.
+
+For ``Counts`` as it stands:
+
 * A token through the model costs 2 FLOPs per weight of every projection
   (attention, MLP, output head; MLA's latent up-projection counted per
   token, as in its expanded form) plus the attention itself: per layer and
@@ -12,6 +22,7 @@ published sizes.  Independent of how the program implements them.
 """
 from __future__ import annotations
 
+import importlib
 from dataclasses import dataclass
 
 _BYTES = {"bfloat16": 2, "float16": 2, "float32": 4}
@@ -43,31 +54,25 @@ class Counts:
             + active * (self.embed_row_bytes + self.vocab * LOGIT_BYTES)
 
 
-def counts(c: dict) -> Counts:
+def stack_counts(c: dict, proj: int, small: int, attn: int, kv: int
+                 ) -> Counts:
+    """A stack of ``num_hidden_layers`` alike layers, each an attention
+    block with ``proj`` projection weights, ``small`` norm gains and biases,
+    ``attn`` FLOPs per context position and ``kv`` cache elements per
+    position, and a SwiGLU MLP of ``intermediate_size``; under an embedding
+    and a head of ``vocab_size`` rows and a final norm."""
     d, f, L = c["hidden_size"], c["intermediate_size"], c["num_hidden_layers"]
-    V, H = c["vocab_size"], c["num_attention_heads"]
-    b = _BYTES[c["torch_dtype"]]
-    if c["reference"] == "mla":
-        dn, dr, dv = c["qk_nope_head_dim"], c["qk_rope_head_dim"], c["v_head_dim"]
-        ql, r = c["q_lora_rank"], c["kv_lora_rank"]
-        proj = d * ql + ql * H * (dn + dr) + d * (r + dr) \
-            + r * H * (dn + dv) + H * dv * d
-        small = 2 * d + ql + r                      # norm gains
-        attn = 2 * H * (dn + dr) + 2 * H * dv
-        kv = L * (r + dr) * b
-    elif c["reference"] == "gqa":
-        Hkv, D = c["num_key_value_heads"], d // H
-        proj = d * H * D + 2 * d * Hkv * D + H * D * d
-        small = 2 * d + H * D + 2 * Hkv * D         # norm gains, q/k/v biases
-        attn = 4 * H * D
-        kv = L * 2 * Hkv * D * b
-    else:
-        raise ValueError(f"no counts for reference {c['reference']!r}")
+    V, b = c["vocab_size"], _BYTES[c["torch_dtype"]]
     layer = proj + 3 * d * f
     head = V * d
     return Counts(matmul_weights=L * layer + head,
                   attn_flops_per_ctx=L * attn,
                   weight_bytes=(L * (layer + small) + head + d) * b,
-                  kv_bytes_per_token=kv,
+                  kv_bytes_per_token=L * kv * b,
                   embed_row_bytes=d * b,
                   vocab=V)
+
+
+def counts(c: dict) -> Counts:
+    mod = importlib.import_module(f".references.{c['reference']}", __package__)
+    return mod.counts(c)

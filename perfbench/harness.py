@@ -7,12 +7,21 @@ and a cell file, each found by name under this directory:
 
 * ``configs/<config>.json``: the published sizes, the ``program`` arguments
   of ``repro.models.ModelConfig`` that run them, and the ``reference``
-  module (``references/<reference>.py``);
+  module (``references/<reference>.py``).  That module knows the model's
+  kind: ``layer_shapes(c)``, ``block(x, w, c, quant)``, ``counts(c)`` (the
+  FLOPs and bytes the roofline readers use) and optionally
+  ``layer_weights(seed, c, layer)`` where the layers come in groups beyond
+  ``blocks`` (see ``references/__init__.py``);
 * ``traffic/<traffic>.json``: the mix (see ``traffic.py``);
 * ``workloads/<cell>.json``: the ``ServeConfig``, the RPC backend, how many
   finished requests the check samples, and the check's limits;
 * ``metrics/<metric>.py``: one reader per metric, ``read(run)`` returning a
-  number or None (nothing to read in this run).
+  number or None (nothing to read in this run).  A metric entry without a
+  ``workloads`` list applies to every cell.
+
+A configuration of a new kind is therefore new files alone: one
+configuration file, one reference module, its cells and its metric
+readers.
 
 Set-up makes the weights from the seed, builds ``build_llm_app`` and sends
 one warm-up request, which compiles the prefill at the bucket, the insert

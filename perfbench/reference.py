@@ -5,7 +5,9 @@ padded with 0 to the prefill bucket, then every served token but the last)
 and runs the configuration's float32 reference over them, one layer at a
 time: layer ``l``'s weights are drawn again from the seed (``weights``), in
 float32, and dropped before the next, so a model whose float32 copy would
-not fit beside anything else still runs.  It reads nothing the program
+not fit beside anything else still runs.  The module's ``layer_weights``
+says which group of stacked layers model layer ``l`` comes from; without
+it every layer is drawn from ``blocks``.  It reads nothing the program
 made.
 
 At each position that produced a served token it reads the *gap*: the
@@ -39,6 +41,12 @@ def _nest(flat: Dict[Tuple[str, ...], jax.Array]) -> dict:
     return out
 
 
+def _uniform_layer(shapes: Dict[Tuple[str, ...], Tuple[int, ...]], seed: int,
+                   c: dict, layer: int) -> Dict[Tuple[str, ...], jax.Array]:
+    return weights.layer_weights(seed, shapes, c["torch_dtype"],
+                                 c["num_hidden_layers"], layer)
+
+
 def _whole_shapes(c: dict) -> Dict[Tuple[str, ...], Tuple[int, ...]]:
     d, V = c["hidden_size"], c["vocab_size"]
     shapes = {("embed",): (V, d), ("ln_f",): (d,)}
@@ -70,9 +78,10 @@ def forward(c: dict, seed: int, seqs: Sequence[np.ndarray], rows: Sequence[np.nd
     table = whole[("lm_head",) if ("lm_head",) in whole else ("embed",)]
     blocks = {q: jax.jit(partial(mod.block, c=c, quant=q)) for q in quants}
     xs = {q: [whole[("embed",)][jnp.asarray(s)] for s in seqs] for q in quants}
-    shapes = mod.layer_shapes(c)
+    draw_layer = getattr(mod, "layer_weights", None) \
+        or partial(_uniform_layer, mod.layer_shapes(c))
     for layer in range(L):
-        w = _nest(weights.layer_weights(seed, shapes, dtype, L, layer))
+        w = _nest(draw_layer(seed, c, layer))
         for q in quants:
             xs[q] = [blocks[q](x, w) for x in xs[q]]
         del w

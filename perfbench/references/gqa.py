@@ -12,6 +12,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..flops import Counts, stack_counts
 from .common import causal_attention, linear, rms_norm, rope, swiglu
 
 
@@ -30,6 +31,15 @@ def layer_shapes(c: dict) -> Dict[Tuple[str, ...], Tuple[int, ...]]:
         ("blocks", "mlp", "w_gate"): (d, f), ("blocks", "mlp", "w_up"): (d, f),
         ("blocks", "mlp", "w_down"): (f, d),
     }
+
+
+def counts(c: dict) -> Counts:
+    d, H = c["hidden_size"], c["num_attention_heads"]
+    Hkv, D = c["num_key_value_heads"], d // H
+    return stack_counts(
+        c, proj=d * H * D + 2 * d * Hkv * D + H * D * d,
+        small=2 * d + H * D + 2 * Hkv * D,          # norm gains, q/k/v biases
+        attn=4 * H * D, kv=2 * Hkv * D)
 
 
 def block(x: jax.Array, w: dict, c: dict, quant: Optional[str]) -> jax.Array:

@@ -17,6 +17,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..flops import Counts, stack_counts
 from .common import causal_attention, linear, rms_norm, rope, swiglu
 
 
@@ -36,6 +37,17 @@ def layer_shapes(c: dict) -> Dict[Tuple[str, ...], Tuple[int, ...]]:
         ("blocks", "mlp", "w_gate"): (d, f), ("blocks", "mlp", "w_up"): (d, f),
         ("blocks", "mlp", "w_down"): (f, d),
     }
+
+
+def counts(c: dict) -> Counts:
+    d, H = c["hidden_size"], c["num_attention_heads"]
+    dn, dr, dv = c["qk_nope_head_dim"], c["qk_rope_head_dim"], c["v_head_dim"]
+    ql, r = c["q_lora_rank"], c["kv_lora_rank"]
+    return stack_counts(
+        c, proj=d * ql + ql * H * (dn + dr) + d * (r + dr)
+        + r * H * (dn + dv) + H * dv * d,
+        small=2 * d + ql + r,                        # norm gains
+        attn=2 * H * (dn + dr) + 2 * H * dv, kv=r + dr)
 
 
 def block(x: jax.Array, w: dict, c: dict, quant: Optional[str]) -> jax.Array:

@@ -1,12 +1,15 @@
 """Seeded weights: the whole tree on the device in one jitted call, and one
 layer again, alone, for the reference.
 
-Leaf ``path`` of shape ``(L, ...)`` under ``blocks`` is drawn layer by layer,
-layer ``l`` from ``fold_in(fold_in(seed_key, crc32(path)), l)``, so the
-reference can draw layer ``l`` by itself and get the very values served,
-without reading anything the program holds.  Values are drawn in float32
-and rounded to the served type; the reference takes that rounding back to
-float32.
+A leaf ``path`` whose top-level key is ``blocks`` or ends in ``_blocks`` (a
+group of stacked layers, such as ``dense_blocks``) has the shape ``(n, ...)``
+and is drawn layer by layer: layer ``i`` of the group from
+``fold_in(fold_in(seed_key, crc32(path)), i)``, the group's name in ``path``.
+So the reference can draw one layer by itself and get the very values
+served, without reading anything the program holds.  ``n_layers``, which
+scales the output projections, is the model's total over all groups.
+Values are drawn in float32 and rounded to the served type; the reference
+takes that rounding back to float32.
 
 Scales follow ``repro.models.init`` (``std`` 0.02, output projections
 ``0.02 / sqrt(2 L)``) with three departures, each so that the comparison
@@ -44,7 +47,6 @@ NORMS = frozenset({"ln1", "ln2", "ln_f", "q_norm", "k_norm", "kv_norm"})
 BIASES = frozenset({"bq", "bk", "bv"})
 OUTPUTS = frozenset({"wo", "w_down"})
 QUERY_KEY = frozenset({"wq", "wk", "wq_b"})
-STACKED = "blocks"
 
 Path = Tuple[str, ...]
 
@@ -85,7 +87,7 @@ def _make(spec, treedef, n_layers: int, key: jax.Array) -> Any:
     leaves = []
     for path, shape, dtype in spec:
         k = leaf_key(key, path)
-        if path[0] == STACKED:
+        if path[0] == "blocks" or path[0].endswith("_blocks"):
             leaf = jax.lax.map(
                 lambda l, k=k, path=path, shape=shape, dtype=dtype: draw(
                     jax.random.fold_in(k, l), path, shape[1:],
@@ -122,7 +124,8 @@ def layer_weights(seed: int, shapes: Dict[Path, Tuple[int, ...]],
                   dtype: Any, n_layers: int, layer: int
                   ) -> Dict[Path, jax.Array]:
     """Layer ``layer`` of each stacked leaf in ``shapes`` (path -> shape of
-    one layer), in float32, as served in ``dtype``."""
+    one layer), in float32, as served in ``dtype``; ``layer`` counts within
+    the leaf's group."""
     key = seed_key(seed)
     lay = jnp.asarray(layer, jnp.uint32)
     return {p: _layer_leaf(key, p, s, jnp.dtype(dtype), n_layers, lay)
