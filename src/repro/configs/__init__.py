@@ -21,6 +21,7 @@ ARCH_IDS: List[str] = [
     "recurrentgemma-9b",
     "qwen2-vl-2b",
     "seamless-m4t-medium",
+    "deepseek-v2-lite",
 ]
 
 

@@ -2,7 +2,7 @@
 
 One dataclass, six families:
   dense   — GQA/MLA decoder LMs (qwen2, qwen3, llama3, minicpm3)
-  moe     — mixture-of-experts decoders (olmoe, grok-1)
+  moe     — mixture-of-experts decoders (olmoe, grok-1, deepseek-v2)
   ssm     — attention-free recurrent LMs (rwkv6)
   hybrid  — RG-LRU + local-attention (recurrentgemma)
   vlm     — M-RoPE decoder backbone, vision frontend stubbed (qwen2-vl)
@@ -10,6 +10,7 @@ One dataclass, six families:
 """
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field, replace
 from typing import Optional, Tuple
 
@@ -35,9 +36,17 @@ class ModelConfig:
     attn_logit_softcap: float = 0.0         # grok-style soft capping
     attn_window: int = 0            # >0: sliding-window (local) attention
 
-    # ---- MLA (multi-head latent attention, minicpm3) -----------------------
+    # ---- YaRN rope scaling (deepseek-v2); yarn_factor 0: plain rope --------
+    yarn_factor: float = 0.0
+    yarn_original_len: int = 0      # original_max_position_embeddings
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
+
+    # ---- MLA (multi-head latent attention, minicpm3, deepseek-v2) ---------
     use_mla: bool = False
-    q_lora_rank: int = 0
+    q_lora_rank: int = 0            # 0: queries straight from x (deepseek-v2-lite)
     kv_lora_rank: int = 0
     qk_nope_dim: int = 0
     qk_rope_dim: int = 0
@@ -47,6 +56,13 @@ class ModelConfig:
     n_experts: int = 0
     experts_per_token: int = 0
     router_aux_coef: float = 0.01
+    moe_norm_topk: bool = True         # renormalise the top-k gate weights
+    n_shared_experts: int = 0          # one SwiGLU of n_shared x d_ff beside
+                                       # the routed experts
+    n_held_experts: int = 0            # routed experts held here (0: all);
+    first_held_expert: int = 0         # ids first .. first + held - 1
+    n_dense_layers: int = 0            # leading dense SwiGLU layers (params
+    dense_d_ff: int = 0                # "dense_blocks"), of this width
     moe_capacity_factor: float = 1.25
     moe_expert_split: int = 1          # "half-expert" sharding: split each
                                        # expert's d_ff k ways so that
@@ -113,6 +129,17 @@ class ModelConfig:
         return self.n_kv_heads * self.head_dim
 
     @property
+    def held_experts(self) -> int:
+        """Routed experts whose weights this chip holds."""
+        return self.n_held_experts or self.n_experts
+
+    @property
+    def yarn_attn_scale(self) -> float:
+        """YaRN's softmax factor mscale(factor, mscale_all_dim)^2; 1 without
+        YaRN or without ``mscale_all_dim``."""
+        return yarn_get_mscale(self.yarn_factor, self.yarn_mscale_all_dim) ** 2
+
+    @property
     def rwkv_n_heads(self) -> int:
         return self.d_model // self.rwkv_head_size
 
@@ -137,12 +164,17 @@ class ModelConfig:
                 assert self.n_heads % self.n_kv_heads == 0
         if self.family == "moe":
             assert self.n_experts > 0 and self.experts_per_token > 0
+            assert 0 <= self.first_held_expert
+            assert self.first_held_expert + self.held_experts <= self.n_experts
+        if self.n_dense_layers:
+            assert 0 < self.n_dense_layers < self.n_layers
+            assert self.dense_d_ff > 0
         if self.family == "ssm":
             assert self.d_model % self.rwkv_head_size == 0
         if self.family == "hybrid":
             assert self.block_pattern and self.lru_width > 0
         if self.use_mla:
-            assert self.q_lora_rank > 0 and self.kv_lora_rank > 0
+            assert self.q_lora_rank >= 0 and self.kv_lora_rank > 0
             assert self.qk_nope_dim > 0 and self.qk_rope_dim > 0
             assert self.v_head_dim > 0
         if self.rope_sections:
@@ -151,6 +183,13 @@ class ModelConfig:
 
     def _rope_dim(self) -> int:
         return self.qk_rope_dim if self.use_mla else self.head_dim
+
+
+def yarn_get_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention magnitude factor, as DeepSeek-V2 publishes it."""
+    if factor <= 1:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
 
 
 @dataclass(frozen=True)

@@ -53,10 +53,14 @@ def _gqa_attn(mk, cfg: ModelConfig, L: int) -> Dict[str, Any]:
 def _mla_attn(mk, cfg: ModelConfig, L: int) -> Dict[str, Any]:
     d, H = cfg.d_model, cfg.n_heads
     dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+    if cfg.q_lora_rank:
+        q = {"wq_a": mk((L, d, cfg.q_lora_rank)),
+             "q_norm": mk((L, cfg.q_lora_rank), kind="ones"),
+             "wq_b": mk((L, cfg.q_lora_rank, H * (dn + dr)))}
+    else:
+        q = {"wq": mk((L, d, H * (dn + dr)))}
     return {
-        "wq_a": mk((L, d, cfg.q_lora_rank)),
-        "q_norm": mk((L, cfg.q_lora_rank), kind="ones"),
-        "wq_b": mk((L, cfg.q_lora_rank, H * (dn + dr))),
+        **q,
         "wkv_a": mk((L, d, cfg.kv_lora_rank + dr)),
         "kv_norm": mk((L, cfg.kv_lora_rank), kind="ones"),
         "wkv_b": mk((L, cfg.kv_lora_rank, H * (dn + dv))),
@@ -64,18 +68,27 @@ def _mla_attn(mk, cfg: ModelConfig, L: int) -> Dict[str, Any]:
     }
 
 
-def _mlp(mk, cfg: ModelConfig, L: int, gated: bool = True) -> Dict[str, Any]:
+def _mlp(mk, cfg: ModelConfig, L: int, gated: bool = True,
+         experts: bool = True) -> Dict[str, Any]:
+    """The MLP of ``L`` stacked layers: routed experts (those held here)
+    with the router over all of them and the shared experts, where the
+    config has experts and ``experts``; else a dense MLP of ``d_ff``."""
     d, f = cfg.d_model, cfg.d_ff
     down_std = 0.02 / (2 * cfg.n_layers) ** 0.5
-    if cfg.n_experts:
-        E = cfg.n_experts * cfg.moe_expert_split
+    if cfg.n_experts and experts:
+        E = cfg.held_experts * cfg.moe_expert_split
         fs = f // cfg.moe_expert_split
-        return {
+        p = {
             "router": mk((L, d, cfg.n_experts), dtype=jnp.float32),
             "w_gate": mk((L, E, d, fs)),
             "w_up": mk((L, E, d, fs)),
             "w_down": mk((L, E, fs, d), std=down_std),
         }
+        if cfg.n_shared_experts:
+            fsh = cfg.n_shared_experts * f
+            p["shared"] = {"w_gate": mk((L, d, fsh)), "w_up": mk((L, d, fsh)),
+                           "w_down": mk((L, fsh, d), std=down_std)}
+        return p
     if gated:
         return {"w_gate": mk((L, d, f)), "w_up": mk((L, d, f)),
                 "w_down": mk((L, f, d), std=down_std)}
@@ -84,18 +97,25 @@ def _mlp(mk, cfg: ModelConfig, L: int, gated: bool = True) -> Dict[str, Any]:
 
 # --------------------------------------------------------------- families
 def _init_lm(mk, cfg: ModelConfig) -> Dict[str, Any]:
-    L, d, V = cfg.n_layers, cfg.d_model, cfg.vocab_size
-    attn = _mla_attn(mk, cfg, L) if cfg.use_mla else _gqa_attn(mk, cfg, L)
-    params = {
-        "embed": mk((V, d)),
-        "ln_f": mk((d,), kind="ones"),
-        "blocks": {
-            "ln1": mk((L, d), kind="ones"),
-            "ln2": mk((L, d), kind="ones"),
-            "attn": attn,
-            "mlp": _mlp(mk, cfg, L),
-        },
-    }
+    """Embedding, head, and the decoder layers: ``n_dense_layers`` leading
+    dense layers of width ``dense_d_ff`` in ``dense_blocks``, the rest in
+    ``blocks``."""
+    d, V, Ld = cfg.d_model, cfg.vocab_size, cfg.n_dense_layers
+
+    def layers(L: int, mlp_cfg: ModelConfig, experts: bool
+               ) -> Dict[str, Any]:
+        blocks = {"ln1": mk((L, d), kind="ones"),
+                  "ln2": mk((L, d), kind="ones")}
+        blocks["attn"] = _mla_attn(mk, cfg, L) if cfg.use_mla \
+            else _gqa_attn(mk, cfg, L)
+        blocks["mlp"] = _mlp(mk, mlp_cfg, L, experts=experts)
+        return blocks
+
+    params = {"embed": mk((V, d)), "ln_f": mk((d,), kind="ones")}
+    if Ld:
+        params["dense_blocks"] = layers(
+            Ld, cfg.with_(d_ff=cfg.dense_d_ff), experts=False)
+    params["blocks"] = layers(cfg.n_layers - Ld, cfg, experts=True)
     if not cfg.tie_embeddings:
         params["lm_head"] = mk((V, d))
     return params

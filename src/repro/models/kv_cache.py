@@ -10,6 +10,16 @@ aligns with scan-over-layers.  Shapes:
   lru     : h (L, B, lru_width) fp32 + conv tail (L, B, cw-1, lru_width)
   encdec  : decoder self full-cache + precomputed cross k/v
 
+A model with leading dense layers (``ModelConfig.n_dense_layers``) keeps
+their cache in leaves of their own, named with the ``dense_`` prefix
+(``dense_latent``, ``dense_k_rope``), beside the other layers' stack: each
+group's decode scan streams its own leaves, and no step concatenates them.
+
+An expert model's cache also carries ``moe_stats`` (1, B, 2) int32: what
+the last prefill or decode step's expert layers counted for each row
+(``layers.moe_dropless``), so that the step's one fetch can read it.  A
+step ignores the value it is given.
+
 ``long_500k`` stays feasible for ssm/hybrid because their state is O(1) in
 sequence length (rwkv/lru) or bounded by the attention window (ring buffer).
 """
@@ -21,6 +31,9 @@ import jax
 import jax.numpy as jnp
 
 from .config import ModelConfig
+
+
+DENSE = "dense_"      # prefix of the leading dense layers' cache leaves
 
 
 def _maybe(shape, dtype, abstract: bool):
@@ -54,9 +67,9 @@ def update_full_cache(ck: jax.Array, cv: jax.Array, k: jax.Array,
 
 
 # -------------------------------------------------------------------- MLA
-def init_mla_cache(cfg: ModelConfig, batch: int, max_len: int,
-                   abstract: bool = False) -> Dict[str, Any]:
-    L = cfg.n_layers
+def init_mla_cache(cfg: ModelConfig, n_layers: int, batch: int,
+                   max_len: int, abstract: bool = False) -> Dict[str, Any]:
+    L = n_layers
     return {
         "latent": _maybe((L, batch, max_len, cfg.kv_lora_rank), cfg.cdtype,
                          abstract),
@@ -144,6 +157,12 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
         return init_hybrid_cache(cfg, batch, abstract)
     if cfg.is_encdec:
         return init_encdec_cache(cfg, batch, max_len, abstract)
-    if cfg.use_mla:
-        return init_mla_cache(cfg, batch, max_len, abstract)
-    return init_full_cache(cfg, cfg.n_layers, batch, max_len, abstract)
+    init = init_mla_cache if cfg.use_mla else init_full_cache
+    nd = cfg.n_dense_layers
+    cache = init(cfg, cfg.n_layers - nd, batch, max_len, abstract)
+    if nd:
+        dense = init(cfg, nd, batch, max_len, abstract)
+        cache.update({DENSE + k: v for k, v in dense.items()})
+    if cfg.n_experts:
+        cache["moe_stats"] = _maybe((1, batch, 2), jnp.int32, abstract)
+    return cache

@@ -5,13 +5,16 @@ recurrence states in fp32.
 """
 from __future__ import annotations
 
+import functools
+import math
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..distributed import shard_hint
-from .config import ModelConfig
+from .config import ModelConfig, yarn_get_mscale
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -47,17 +50,46 @@ def rope_freqs(head_dim: int, theta: float) -> jax.Array:
                                        dtype=jnp.float32) / head_dim))
 
 
+@functools.lru_cache(maxsize=None)
+def yarn_rope(cfg: ModelConfig) -> Tuple[np.ndarray, float]:
+    """YaRN's inverse frequencies (D/2,) over the rotary channels, and the
+    factor on cos and sin, as DeepSeek-V2 publishes them: a linear ramp
+    between the correction dims of ``beta_fast`` and ``beta_slow``
+    rotations at the original length takes each channel from its own
+    frequency (fast channels) to that frequency over ``factor`` (slow
+    ones).  Computed once per configuration."""
+    d, base, f = cfg.qk_rope_dim, cfg.rope_theta, cfg.yarn_factor
+    extra = 1.0 / base ** (np.arange(0, d, 2, dtype=np.float64) / d)
+
+    def corr_dim(rotations: float) -> float:
+        return d * math.log(cfg.yarn_original_len
+                            / (rotations * 2 * math.pi)) / (2 * math.log(base))
+
+    low = max(math.floor(corr_dim(cfg.yarn_beta_fast)), 0)
+    high = min(math.ceil(corr_dim(cfg.yarn_beta_slow)), d - 1)
+    ramp = np.clip((np.arange(d // 2) - low) / max(high - low, 1e-3), 0, 1)
+    inv = extra / f * ramp + extra * (1 - ramp)
+    scale = yarn_get_mscale(f, cfg.yarn_mscale) \
+        / yarn_get_mscale(f, cfg.yarn_mscale_all_dim)
+    inv = inv.astype(np.float32)
+    inv.setflags(write=False)           # one array per config, shared
+    return inv, scale
+
+
 def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
-               sections: Tuple[int, ...] = ()) -> jax.Array:
+               sections: Tuple[int, ...] = (),
+               yarn: Optional[Tuple[np.ndarray, float]] = None) -> jax.Array:
     """Rotary embedding (NeoX half-rotation layout).
 
     x: (B, S, H, D); positions: (B, S) — or (3, B, S) for M-RoPE, where the
     three planes are the temporal / height / width position components and
     ``sections`` splits the D/2 frequency channels among them (Qwen2-VL).
+    ``yarn``: frequencies and cos/sin factor from :func:`yarn_rope`.
     """
     dtype = x.dtype
     d = x.shape[-1]
-    inv = rope_freqs(d, theta)                                  # (D/2,)
+    inv = rope_freqs(d, theta) if yarn is None \
+        else jnp.asarray(yarn[0])                               # (D/2,)
     if positions.ndim == 3:
         assert sections and sum(sections) == d // 2, (sections, d)
         # freqs per component plane, then select by section
@@ -71,6 +103,8 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
         freqs = positions[..., None].astype(jnp.float32) * inv  # (B, S, D/2)
     cos = jnp.cos(freqs)[:, :, None, :]                         # (B, S, 1, D/2)
     sin = jnp.sin(freqs)[:, :, None, :]
+    if yarn is not None and yarn[1] != 1.0:
+        cos, sin = cos * yarn[1], sin * yarn[1]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin,
                            x2 * cos + x1 * sin], axis=-1)
@@ -318,14 +352,25 @@ def gqa_attention_block(x: jax.Array, p: dict, cfg: ModelConfig,
 
 
 # ----------------------------------------------------------------- MLA
+def mla_rope(x: jax.Array, positions: jax.Array,
+             cfg: ModelConfig) -> jax.Array:
+    """Rotary embedding of MLA's rotary channels, YaRN-scaled if set."""
+    return apply_rope(x, positions, cfg.rope_theta,
+                      yarn=yarn_rope(cfg) if cfg.yarn_factor else None)
+
+
 def mla_project_q(x: jax.Array, p: dict, cfg: ModelConfig,
                   positions: jax.Array) -> jax.Array:
-    """Queries through the low-rank path: (B,S,H,nope+rope)."""
+    """Queries (B,S,H,nope+rope): through the low-rank path, or straight
+    from ``x`` by ``wq`` where ``q_lora_rank`` is 0."""
     B, S, _ = x.shape
     H, dn, dr = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
-    ql = rms_norm(linear(x, p["wq_a"]), p["q_norm"], cfg.norm_eps)
-    q = linear(ql, p["wq_b"]).reshape(B, S, H, dn + dr)
-    q_rope = apply_rope(q[..., dn:], positions, cfg.rope_theta)
+    if cfg.q_lora_rank:
+        ql = rms_norm(linear(x, p["wq_a"]), p["q_norm"], cfg.norm_eps)
+        q = linear(ql, p["wq_b"]).reshape(B, S, H, dn + dr)
+    else:
+        q = linear(x, p["wq"]).reshape(B, S, H, dn + dr)
+    q_rope = mla_rope(q[..., dn:], positions, cfg)
     q = jnp.concatenate([q[..., :dn], q_rope], axis=-1)
     return shard_hint(q, "batch", None, "tp", None)
 
@@ -336,8 +381,7 @@ def mla_latent(x: jax.Array, p: dict, cfg: ModelConfig,
     This pair is exactly what MLA caches for decode."""
     kv_a = linear(x, p["wkv_a"])
     latent = rms_norm(kv_a[..., :cfg.kv_lora_rank], p["kv_norm"], cfg.norm_eps)
-    k_rope = apply_rope(kv_a[..., None, cfg.kv_lora_rank:], positions,
-                        cfg.rope_theta)
+    k_rope = mla_rope(kv_a[..., None, cfg.kv_lora_rank:], positions, cfg)
     return latent, k_rope
 
 
@@ -357,14 +401,14 @@ def mla_attend(qq: jax.Array, latent: jax.Array, k_rope: jax.Array,
 
     so the only T-sized tensors are the latent (r=256/channel) and the
     shared rotary key — exactly what the MLA cache stores.  Long q is
-    chunked like :func:`sdpa`.
+    chunked like :func:`sdpa`.  The softmax scale carries YaRN's mscale².
     """
     B, S = qq.shape[:2]
     H, dn, dr, dv = (cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim,
                      cfg.v_head_dim)
     r = cfg.kv_lora_rank
     T = latent.shape[1]
-    scale = (dn + dr) ** -0.5
+    scale = (dn + dr) ** -0.5 * cfg.yarn_attn_scale
 
     w = p["wkv_b"].reshape(r, H, dn + dv)
     w_uk, w_uv = w[..., :dn], w[..., dn:]
@@ -448,28 +492,65 @@ def gelu_mlp(x: jax.Array, p: dict) -> jax.Array:
 
 
 # ------------------------------------------------------------------ MoE
+def moe_route(x: jax.Array, p: dict, cfg: ModelConfig
+              ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The gate over all ``n_experts`` routed experts, in float32 from the
+    float32 router: softmax probabilities (..., E) and each token's top-k
+    weights and expert ids (..., K), the weights renormalised to sum to 1
+    where ``moe_norm_topk`` says so."""
+    logits = jnp.einsum("...d,de->...e", x.astype(jnp.float32),
+                        p["router"].astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_w, top_e = jax.lax.top_k(probs, cfg.experts_per_token)
+    if cfg.moe_norm_topk:
+        top_w = top_w / jnp.clip(top_w.sum(-1, keepdims=True), 1e-9)
+    return probs, top_w, top_e
+
+
+def _held_id(top_e: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """Each assignment's index among the held experts; ``held_experts``
+    (past the last) for an expert another chip holds."""
+    local = top_e - cfg.first_held_expert
+    held = (local >= 0) & (local < cfg.held_experts)
+    return jnp.where(held, local, cfg.held_experts)
+
+
+def _expert_weights(p: dict, cfg: ModelConfig
+                    ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """(..., E, d, f), (..., E, d, f), (..., E, f, d): half-expert weights
+    (stored as ``moe_expert_split`` sub-experts per expert) joined back
+    along f."""
+    ks = cfg.moe_expert_split
+    wg, wu, wd = p["w_gate"], p["w_up"], p["w_down"]
+    if ks == 1:
+        return wg, wu, wd
+    *lead, Eks, d, fs = wg.shape
+    shape = tuple(lead) + (Eks // ks,)
+    join = lambda w: w.reshape(shape + (ks, d, fs)).swapaxes(-3, -2) \
+        .reshape(shape + (d, ks * fs))                           # noqa: E731
+    return join(wg), join(wu), wd.reshape(shape + (ks * fs, d))
+
+
 def moe_ffn(x: jax.Array, p: dict, cfg: ModelConfig,
             capacity_factor: Optional[float] = None
             ) -> Tuple[jax.Array, jax.Array]:
-    """Top-k routed SwiGLU experts, sort-based capacity dispatch.
+    """Top-k routed SwiGLU experts, sort-based capacity dispatch (training).
 
     Tokens are grouped per batch row; within each group, (token, expert)
     assignments are sorted by expert id and scattered into an
     (E, C) capacity buffer — pure XLA ops (argsort/cumsum/scatter), no ragged
     support needed.  Over-capacity assignments are dropped (counted in the
-    aux output).  Returns (output, aux_loss).
+    aux output), and so are assignments to experts not held here.  Returns
+    (output, aux_loss).  The served path uses :func:`moe_dropless`.
     """
     B, S, d = x.shape
-    E, K = cfg.n_experts, cfg.experts_per_token
+    E, El, K = cfg.n_experts, cfg.held_experts, cfg.experts_per_token
     cf = capacity_factor if capacity_factor is not None \
         else cfg.moe_capacity_factor
     C = max(int(S * K / E * cf), K)
 
-    gate_logits = linear(x, p["router"].astype(x.dtype)) \
-        .astype(jnp.float32)                                       # (B,S,E)
-    probs = jax.nn.softmax(gate_logits, axis=-1)
-    top_w, top_e = jax.lax.top_k(probs, K)                         # (B,S,K)
-    top_w = top_w / jnp.clip(top_w.sum(-1, keepdims=True), 1e-9)   # renorm
+    probs, top_w, top_e = moe_route(x, p, cfg)                     # (B,S,K)
 
     # ---- load-balancing aux loss (Switch-style), fp32
     me = probs.mean(axis=(0, 1))                                   # (E,)
@@ -478,7 +559,7 @@ def moe_ffn(x: jax.Array, p: dict, cfg: ModelConfig,
     aux = cfg.router_aux_coef * E * jnp.sum(me * ce)
 
     # ---- per-group sort-based dispatch
-    flat_e = top_e.reshape(B, S * K)                               # (B, N)
+    flat_e = _held_id(top_e, cfg).reshape(B, S * K)                # (B, N)
     flat_w = top_w.reshape(B, S * K).astype(x.dtype)
     tok_id = jnp.repeat(jnp.arange(S), K)[None].repeat(B, 0)       # (B, N)
 
@@ -496,8 +577,8 @@ def moe_ffn(x: jax.Array, p: dict, cfg: ModelConfig,
         [jnp.ones((B, 1), bool), e_sorted[:, 1:] != e_sorted[:, :-1]], axis=1)
     seg_start = jax.lax.cummax(jnp.where(is_new, idx, 0), axis=1)
     pos_in_e = idx - seg_start
-    keep = pos_in_e < C
-    slot = e_sorted * C + jnp.where(keep, pos_in_e, 0)             # (B,N)
+    keep = (pos_in_e < C) & (e_sorted < El)
+    slot = jnp.where(keep, e_sorted * C + pos_in_e, 0)             # (B,N)
 
     # gathers/scatters are expressed per batch row via vmap so they lower
     # with operand batching dims — GSPMD keeps the batch axis sharded
@@ -506,9 +587,9 @@ def moe_ffn(x: jax.Array, p: dict, cfg: ModelConfig,
     # of fp32 all-reduce on grok-1 before this change)
     xs = jax.vmap(lambda row, t: jnp.take(row, t, axis=0))(
         x, t_sorted)                                               # (B,N,d)
-    buf = jax.vmap(lambda s, v: jnp.zeros((E * C, d), x.dtype)
+    buf = jax.vmap(lambda s, v: jnp.zeros((El * C, d), x.dtype)
                    .at[s].add(v))(slot, jnp.where(keep[..., None], xs, 0))
-    buf = buf.reshape(B, E, C, d)
+    buf = buf.reshape(B, El, C, d)
     buf = shard_hint(buf, "batch", "experts", None, None)
 
     # ---- expert SwiGLU: (B,E,C,d) x (E,d,f)
@@ -525,13 +606,13 @@ def moe_ffn(x: jax.Array, p: dict, cfg: ModelConfig,
                                    p["w_gate"].astype(x.dtype))) \
             * jnp.einsum("becd,edf->becf", bufs, p["w_up"].astype(x.dtype))
         y_s = jnp.einsum("becf,efd->becd", h, p["w_down"].astype(x.dtype))
-        y_e = y_s.reshape(B, E, ks, C, d).sum(axis=2)
+        y_e = y_s.reshape(B, El, ks, C, d).sum(axis=2)
     else:
         h = jax.nn.silu(jnp.einsum("becd,edf->becf", buf,
                                    p["w_gate"].astype(x.dtype))) \
             * jnp.einsum("becd,edf->becf", buf, p["w_up"].astype(x.dtype))
         y_e = jnp.einsum("becf,efd->becd", h, p["w_down"].astype(x.dtype))
-    y_e = shard_hint(y_e, "batch", "experts", None, None).reshape(B, E * C, d)
+    y_e = shard_hint(y_e, "batch", "experts", None, None).reshape(B, El * C, d)
 
     # ---- gather back + weighted combine (vmap'd: batch stays sharded)
     ys = jax.vmap(lambda row, s: jnp.take(row, s, axis=0))(y_e, slot)
@@ -539,6 +620,74 @@ def moe_ffn(x: jax.Array, p: dict, cfg: ModelConfig,
     out = jax.vmap(lambda t, v: jnp.zeros((S, d), x.dtype)
                    .at[t].add(v))(t_sorted, ys)
     return shard_hint(out, "batch", "seq", None), aux
+
+
+def moe_dropless(x: jax.Array, p: dict, cfg: ModelConfig
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """Routed SwiGLU experts with nothing dropped (prefill and decode).
+
+    Routes every token over all ``n_experts`` (:func:`moe_route`), sorts the
+    call's assignments to the experts held here by expert, across the whole
+    batch, and runs the held experts as grouped matrix products over
+    exactly those rows (``jax.lax.ragged_dot``).  Assignments to experts
+    another chip holds sort last, outside every group, and add nothing.
+    With ``p["layer"]`` the expert weights are the whole stacks of every
+    layer, (L, E, ...), and that layer's experts are used.
+    Returns the weighted sum (B,S,d) and, per batch row, int32 (B, 2): the
+    row's assignments to held experts, and the largest held expert's load
+    in the call (the same in every row).
+    """
+    B, S, d = x.shape
+    N, K, El = B * S, cfg.experts_per_token, cfg.held_experts
+    xf = x.reshape(N, d)
+    with jax.named_scope("router"):
+        _, top_w, top_e = moe_route(xf, p, cfg)                    # (N, K)
+        gid = _held_id(top_e, cfg).reshape(N * K)
+        order = jnp.argsort(gid, stable=True)
+        held = gid[order] < El                                     # (N*K,)
+        w = jnp.where(held, top_w.reshape(N * K)[order], 0.0)
+        loads = jnp.zeros((El + 1,), jnp.int32).at[gid].add(1)[:El]
+        xs = jnp.take(xf, order // K, axis=0)                      # (N*K, d)
+    with jax.named_scope("experts"):
+        wg, wu, wd = _expert_weights(p, cfg)
+        sizes = loads
+        if "layer" in p:
+            # whole stacks (L, E, ...) as one group per layer and expert:
+            # the other layers' groups are empty, so the grouped product
+            # reads this layer's experts in place and no slice is copied
+            n = wg.shape[0] * El
+            wg, wu, wd = (a.reshape((n,) + a.shape[2:]) for a in (wg, wu, wd))
+            sizes = jax.lax.dynamic_update_slice(
+                jnp.zeros((n,), jnp.int32), loads, (p["layer"] * El,))
+        h = jax.nn.silu(jax.lax.ragged_dot(xs, wg.astype(x.dtype), sizes)) \
+            * jax.lax.ragged_dot(xs, wu.astype(x.dtype), sizes)
+        ys = jax.lax.ragged_dot(h, wd.astype(x.dtype), sizes)      # (N*K, d)
+    ys = jnp.where(held[:, None], ys.astype(jnp.float32) * w[:, None], 0.0)
+    back = jnp.zeros((N * K,), jnp.int32).at[order].set(
+        jnp.arange(N * K, dtype=jnp.int32))                        # inverse
+    out = jnp.take(ys, back, axis=0).reshape(N, K, d).sum(1)
+    out = out.astype(x.dtype).reshape(B, S, d)
+    row_local = (gid < El).reshape(B, S * K).sum(-1, dtype=jnp.int32)
+    stats = jnp.stack([row_local, jnp.broadcast_to(loads.max(), (B,))], -1)
+    return shard_hint(out, "batch", "seq", None), stats
+
+
+def moe_mlp(x: jax.Array, p: dict, cfg: ModelConfig, dropless: bool
+            ) -> Tuple[jax.Array, jax.Array, Optional[jax.Array]]:
+    """An expert layer's MLP: the routed experts, dropless (serving) or by
+    capacity with the aux loss (training), plus the shared experts, which
+    every token passes through.  Returns (output, aux_loss, stats); stats
+    as :func:`moe_dropless` gives them, None in training."""
+    if dropless:
+        out, stats = moe_dropless(x, p, cfg)
+        aux = jnp.zeros((), jnp.float32)
+    else:
+        out, aux = moe_ffn(x, p, cfg)
+        stats = None
+    if cfg.n_shared_experts:
+        with jax.named_scope("shared_experts"):
+            out = out + swiglu_mlp(x, p["shared"])
+    return out, aux, stats
 
 
 # ------------------------------------------------------------- embeddings

@@ -48,16 +48,21 @@ class Model:
         return count_params(self.cfg)
 
     def active_params(self) -> int:
-        """Active (per-token) parameter count — MoE uses top-k experts."""
+        """Parameters a token passes through here.  In each expert layer a
+        token picks ``experts_per_token`` of the ``n_experts`` routed
+        experts, so of the experts held here it passes through
+        ``experts_per_token * held / n_experts`` on average (rounded down
+        in parameters); leading dense layers and shared experts count
+        whole."""
         cfg = self.cfg
         total = self.count_params()
         if not cfg.n_experts:
             return total
-        # expert weights: 3 matrices per expert per layer
         per_expert = 3 * cfg.d_model * cfg.d_ff
-        inactive = cfg.n_layers * (cfg.n_experts - cfg.experts_per_token) \
-            * per_expert
-        return total - inactive
+        held = cfg.held_experts
+        active = per_expert * cfg.experts_per_token * held // cfg.n_experts
+        n_moe = cfg.n_layers - cfg.n_dense_layers
+        return total - n_moe * (held * per_expert - active)
 
     # ------------------------------------------------------------- train
     def loss(self, params: Dict[str, Any], batch: Dict[str, jax.Array]

@@ -2,27 +2,48 @@
 
 All variants share one block body; layers are stacked and driven by
 ``jax.lax.scan`` (compact HLO at 126 layers), with optional per-layer remat.
+A model with leading dense layers before its expert layers (deepseek-v2)
+keeps them as a second group, ``dense_blocks``, scanned first, with their
+cache in ``dense_``-prefixed leaves of their own.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Tuple
+from functools import partial
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ..distributed import shard_hint
 from .config import ModelConfig
-from .kv_cache import update_full_cache, update_mla_cache
+from .kv_cache import DENSE, update_full_cache, update_mla_cache
 from .layers import (attention_scores_mask, embed_tokens, gqa_attend,
                      gqa_project, lm_logits, mla_attend, mla_latent,
-                     mla_project_q, moe_ffn, rms_norm, swiglu_mlp)
+                     mla_project_q, moe_mlp, rms_norm, swiglu_mlp)
 
 
 # ------------------------------------------------------------------ blocks
+def _mlp(h: jax.Array, p: Dict[str, Any], cfg: ModelConfig, dropless: bool
+         ) -> Tuple[jax.Array, jax.Array, Optional[jax.Array]]:
+    """A layer's MLP: an expert layer where it has a router, else a dense
+    SwiGLU.  Returns (output, aux_loss, expert stats or None)."""
+    if "router" in p:
+        return moe_mlp(h, p, cfg, dropless)
+    return swiglu_mlp(h, p), jnp.zeros((), jnp.float32), None
+
+
+def _add(total: Optional[jax.Array], stats: Optional[jax.Array]
+         ) -> Optional[jax.Array]:
+    return total if stats is None else total + stats
+
+
 def block_fwd(x: jax.Array, p: Dict[str, Any], cfg: ModelConfig,
-              positions: jax.Array, mask: jax.Array
-              ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array], jax.Array]:
-    """One decoder block (train/prefill). Returns (x, kv_for_cache, aux).
+              positions: jax.Array, mask: jax.Array, dropless: bool = False
+              ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array], jax.Array,
+                         Optional[jax.Array]]:
+    """One decoder block (train/prefill). Returns (x, kv_for_cache, aux,
+    expert stats or None).  ``dropless``: the serving expert layer, else
+    the training one (capacity dispatch and its aux loss).
 
     The residual stream is re-constrained at the block boundary with the
     "carry_seq" logical axis: when a per-arch rule maps it to "model", the
@@ -48,22 +69,21 @@ def block_fwd(x: jax.Array, p: Dict[str, Any], cfg: ModelConfig,
         x = x + attn
     with jax.named_scope("mlp"):
         h = rms_norm(x, p["ln2"], cfg.norm_eps)
-        if cfg.n_experts:
-            ff, aux = moe_ffn(h, p["mlp"], cfg)
-        else:
-            ff, aux = swiglu_mlp(h, p["mlp"]), jnp.zeros((), jnp.float32)
+        ff, aux, stats = _mlp(h, p["mlp"], cfg, dropless)
         out = x + ff
     if out.shape[1] > 1:
         out = shard_hint(out, "batch", "carry_seq", None)  # boundary carry
-    return out, kv, aux
+    return out, kv, aux, stats
 
 
 def block_decode(x: jax.Array, p: Dict[str, Any], cfg: ModelConfig,
                  cache_l: Dict[str, jax.Array], pos: jax.Array
-                 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+                 ) -> Tuple[jax.Array, Dict[str, jax.Array],
+                            Optional[jax.Array]]:
     """One decoder block, single-token decode against the layer's KV cache.
 
-    x: (B,1,d); pos: (B,) absolute position of the new token.
+    x: (B,1,d); pos: (B,) absolute position of the new token.  Returns
+    (x, the layer's new cache, expert stats or None).
     """
     B = x.shape[0]
     positions = pos[:, None]                                    # (B,1)
@@ -92,11 +112,8 @@ def block_decode(x: jax.Array, p: Dict[str, Any], cfg: ModelConfig,
         x = x + attn
     with jax.named_scope("mlp"):
         h = rms_norm(x, p["ln2"], cfg.norm_eps)
-        if cfg.n_experts:
-            ff, _ = moe_ffn(h, p["mlp"], cfg)
-        else:
-            ff = swiglu_mlp(h, p["mlp"])
-        return x + ff, new_cache
+        ff, _, stats = _mlp(h, p["mlp"], cfg, dropless=True)
+        return x + ff, new_cache, stats
 
 
 def _cache_mask(pos: jax.Array, max_len: int) -> jax.Array:
@@ -127,6 +144,58 @@ def embed_inputs(params: Dict[str, Any], cfg: ModelConfig,
     return x.astype(cfg.cdtype), positions
 
 
+def _groups(params: Dict[str, Any]) -> Tuple[str, ...]:
+    """The stacked layer groups in model order: the leading dense layers
+    (``dense_blocks``), if any, then ``blocks``."""
+    return tuple(g for g in ("dense_blocks", "blocks") if g in params)
+
+
+def _cache_of(cache: Dict[str, jax.Array], group: str
+              ) -> Dict[str, jax.Array]:
+    """One layer group's cache leaves, by their names within the group."""
+    if group == "dense_blocks":
+        return {k[len(DENSE):]: v for k, v in cache.items()
+                if k.startswith(DENSE)}
+    return {k: v for k, v in cache.items()
+            if not k.startswith(DENSE) and k != "moe_stats"}
+
+
+def _named(group: str, cache_g: Dict[str, jax.Array]
+           ) -> Dict[str, jax.Array]:
+    """One group's cache leaves under their names in the whole cache."""
+    prefix = DENSE if group == "dense_blocks" else ""
+    return {prefix + k: v for k, v in cache_g.items()}
+
+
+EXPERTS = ("w_gate", "w_up", "w_down")
+
+
+def _served(p_group: Dict[str, Any]
+            ) -> Tuple[Dict[str, Any], Optional[Dict[str, jax.Array]]]:
+    """A group's weights as the served scans take them: the per-layer
+    stacks for the scan's xs, and apart from them the routed experts'
+    stacks (None in a group without experts), which the expert layer
+    indexes whole, so that its grouped products read them in place."""
+    if "router" not in p_group["mlp"]:
+        return p_group, None
+    mlp = dict(p_group["mlp"])
+    whole = {k: mlp.pop(k) for k in EXPERTS}
+    return dict(p_group, mlp=mlp), whole
+
+
+def _layer(p_l: Dict[str, Any], whole: Optional[Dict[str, jax.Array]],
+           i: Any = None) -> Dict[str, Any]:
+    """Layer ``i``'s weights: its slice, and the experts' whole stacks."""
+    if whole is None:
+        return p_l
+    return dict(p_l, mlp=dict(p_l["mlp"], **whole, layer=i))
+
+
+def _stats0(cfg: ModelConfig, batch: int) -> Optional[jax.Array]:
+    """Zero expert stats (B, 2) for a model with experts, else None."""
+    return jnp.zeros((batch, 2), jnp.int32) if cfg.n_experts else None
+
+
 def forward(params: Dict[str, Any], cfg: ModelConfig,
             inputs: Dict[str, jax.Array]) -> Tuple[jax.Array, jax.Array]:
     """Full-sequence forward (train). Returns (hidden, aux_loss)."""
@@ -138,12 +207,14 @@ def forward(params: Dict[str, Any], cfg: ModelConfig,
 
     def body(carry, p_l):
         h, aux = carry
-        h2, _, aux_l = block_fwd(h, p_l, cfg, positions, mask)
+        h2, _, aux_l, _ = block_fwd(h, p_l, cfg, positions, mask)
         return (h2, aux + aux_l), None
 
     body_fn = jax.checkpoint(body) if cfg.remat else body
-    (x, aux), _ = jax.lax.scan(body_fn, (x, jnp.zeros((), jnp.float32)),
-                               params["blocks"])
+    carry = (x, jnp.zeros((), jnp.float32))
+    for group in _groups(params):
+        carry, _ = jax.lax.scan(body_fn, carry, params[group])
+    x, aux = carry
     x = shard_hint(x, "batch", None, None)
     x = rms_norm(x, params["ln_f"], cfg.norm_eps)
     return x, aux
@@ -152,25 +223,38 @@ def forward(params: Dict[str, Any], cfg: ModelConfig,
 def prefill(params: Dict[str, Any], cfg: ModelConfig,
             inputs: Dict[str, jax.Array]
             ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Prefill: forward + emit the per-layer KV cache.
+    """Prefill: forward + emit the per-layer KV cache (every group's layers
+    in model order), and for an expert model ``moe_stats``.
     Returns (last-token logits (B,V), cache)."""
     x, positions = embed_inputs(params, cfg, inputs)
     mask = None   # masks are built lazily (chunked) inside the attention
 
-    def body(h, p_l):
-        h2, kv, _ = block_fwd(h, p_l, cfg, positions, mask)
-        return h2, kv
+    def body(carry, xs, whole=None):
+        h, st = carry
+        p_l, *i = xs
+        h2, kv, _, stats = block_fwd(h, _layer(p_l, whole, *i), cfg,
+                                     positions, mask, dropless=True)
+        return (h2, _add(st, stats)), kv
 
-    body_fn = jax.checkpoint(body) if cfg.remat else body
-    x, kvs = jax.lax.scan(body_fn, x, params["blocks"])
+    carry, cache = (x, _stats0(cfg, x.shape[0])), {}
+    for group in _groups(params):
+        p_xs, whole = _served(params[group])
+        L = jax.tree.leaves(p_xs)[0].shape[0]
+        xs = (p_xs,) if whole is None else (p_xs, jnp.arange(L))
+        fn = partial(body, whole=whole)
+        carry, kvs = jax.lax.scan(jax.checkpoint(fn) if cfg.remat else fn,
+                                  carry, xs)
+        if cfg.use_mla:
+            cache.update(_named(group, {"latent": kvs[0], "k_rope": kvs[1]}))
+        else:
+            cache.update(_named(group, {"k": kvs[0], "v": kvs[1]}))
+    x, stats = carry
     with jax.named_scope("head"):
         x = rms_norm(x, params["ln_f"], cfg.norm_eps)
         logits = lm_logits(x[:, -1:], _out_table(params, cfg),
                            cfg.logit_softcap)
-    if cfg.use_mla:
-        cache = {"latent": kvs[0], "k_rope": kvs[1]}
-    else:
-        cache = {"k": kvs[0], "v": kvs[1]}
+    if stats is not None:
+        cache["moe_stats"] = stats[None]
     return logits[:, 0], cache
 
 
@@ -178,40 +262,59 @@ def decode_step(params: Dict[str, Any], cfg: ModelConfig,
                 cache: Dict[str, jax.Array], tokens: jax.Array,
                 pos: jax.Array
                 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """One decode step. tokens: (B,1); pos: (B,). Returns (logits(B,V), cache)."""
+    """One decode step. tokens: (B,1); pos: (B,). Returns (logits(B,V), cache).
+
+    Each layer group scans its own weights and its own cache leaves, so the
+    leading dense layers and the rest each stream their cache xs -> ys.
+    """
     x = embed_tokens(tokens, params["embed"], scale=cfg.embed_scale)
     x = x.astype(cfg.cdtype)
+    carry, new_cache = (x, _stats0(cfg, x.shape[0])), {}
 
-    if cfg.decode_carry_cache:
-        # §Perf variant: thread the whole cache through the scan *carry* so
-        # XLA updates it in place (one buffer), instead of streaming it as
-        # xs -> stacked ys (two buffers: 2x cache HBM at 405B/32k).
-        def body_carry(carry, xs):
-            h, c = carry
-            p_l, i = xs
-            cache_l = jax.tree.map(
-                lambda a: jax.lax.dynamic_index_in_dim(a, i, 0,
-                                                       keepdims=False), c)
-            h2, new_cache_l = block_decode(h, p_l, cfg, cache_l, pos)
-            c = jax.tree.map(
-                lambda a, n: jax.lax.dynamic_update_index_in_dim(
-                    a, n.astype(a.dtype), i, 0), c, new_cache_l)
-            return (h2, c), None
+    # §Perf variant (decode_carry_cache): thread the whole cache through
+    # the scan *carry* so XLA updates it in place (one buffer), instead of
+    # streaming it as xs -> stacked ys (two buffers: 2x cache HBM at
+    # 405B/32k).
+    def body_carry(carry, xs, whole=None):
+        h, st, c = carry
+        p_l, i = xs
+        cache_l = jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
+            c)
+        h2, new_cache_l, s = block_decode(h, _layer(p_l, whole, i), cfg,
+                                          cache_l, pos)
+        c = jax.tree.map(
+            lambda a, n: jax.lax.dynamic_update_index_in_dim(
+                a, n.astype(a.dtype), i, 0), c, new_cache_l)
+        return (h2, _add(st, s), c), None
 
-        L = jax.tree.leaves(cache)[0].shape[0]
-        (x, new_cache), _ = jax.lax.scan(
-            body_carry, (x, cache),
-            (params["blocks"], jnp.arange(L, dtype=jnp.int32)))
-    else:
-        def body(h, xs):
-            p_l, cache_l = xs
-            h2, new_cache_l = block_decode(h, p_l, cfg, cache_l, pos)
-            return h2, new_cache_l
+    def body(carry, xs, whole=None):
+        h, st = carry
+        p_l, cache_l, *i = xs
+        h2, new_cache_l, s = block_decode(h, _layer(p_l, whole, *i), cfg,
+                                          cache_l, pos)
+        return (h2, _add(st, s)), new_cache_l
 
-        x, new_cache = jax.lax.scan(body, x, (params["blocks"], cache))
+    for group in _groups(params):
+        kv = _cache_of(cache, group)
+        p_xs, whole = _served(params[group])
+        L = jax.tree.leaves(kv)[0].shape[0]
+        if cfg.decode_carry_cache:
+            (h, st, kv), _ = jax.lax.scan(
+                partial(body_carry, whole=whole), carry + (kv,),
+                (p_xs, jnp.arange(L, dtype=jnp.int32)))
+            carry = (h, st)
+        else:
+            xs = (p_xs, kv) if whole is None \
+                else (p_xs, kv, jnp.arange(L, dtype=jnp.int32))
+            carry, kv = jax.lax.scan(partial(body, whole=whole), carry, xs)
+        new_cache.update(_named(group, kv))
+    x, stats = carry
     with jax.named_scope("head"):
         x = rms_norm(x, params["ln_f"], cfg.norm_eps)
         logits = lm_logits(x, _out_table(params, cfg), cfg.logit_softcap)
+    if stats is not None:
+        new_cache["moe_stats"] = stats[None]
     return logits[:, 0], new_cache
 
 

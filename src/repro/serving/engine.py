@@ -24,7 +24,13 @@ and ``decode.commit``, and ``engine.lock_wait`` for each wait on the lock.
 
 Greedy tokens are picked on the device by ``_greedy_pick``, a program of
 its own applied to whatever ``_prefill``/``_decode`` return, so the host
-copies one id per slot and not a row of ``vocab`` logits.
+copies one id per slot and not a row of ``vocab`` logits.  For an expert
+model the pick also appends the step's two expert counts from the cache's
+``moe_stats``, so they come back in the same copy; ``engine.prefill`` and
+``engine.decode_step`` carry them as ``moe_local`` (assignments to the
+experts held here, summed over expert layers and over every row the step
+computed) and ``moe_max_load`` (the largest held expert's load, summed
+over expert layers).
 """
 from __future__ import annotations
 
@@ -65,9 +71,28 @@ class _Request:
     rid: Optional[int] = None     # trace id of its spans (traced runs)
 
 
-def _greedy_pick(logits: jax.Array) -> jax.Array:
-    """Each row's largest logit's id, the lowest on ties, as ``np.argmax``."""
-    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+def _greedy_pick(logits: jax.Array,
+                 moe_stats: Optional[jax.Array] = None) -> jax.Array:
+    """Each row's largest logit's id, the lowest on ties, as ``np.argmax``;
+    with ``moe_stats`` (1, B, 2) then its rows' held assignments summed and
+    the largest held load: (B + 2,) int32."""
+    ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    if moe_stats is None:
+        return ids
+    return jnp.concatenate([ids, moe_stats[0, :, 0].sum(keepdims=True),
+                            moe_stats[0, :1, 1]])
+
+
+def _moe_stats(cache: Any) -> Tuple[jax.Array, ...]:
+    """The arguments that make ``_greedy_pick`` append the expert counts."""
+    return (cache["moe_stats"],) if "moe_stats" in cache else ()
+
+
+def _moe_attrs(ids: np.ndarray, n: int) -> dict:
+    """The expert counts that follow the ``n`` ids, if the pick added them."""
+    if len(ids) == n:
+        return {}
+    return {"moe_local": int(ids[n]), "moe_max_load": int(ids[n + 1])}
 
 
 def _end_request(h: instrument.Hooks, rid: int, _done: Future) -> None:
@@ -178,7 +203,7 @@ class InferenceEngine:
         logits, pcache = self._prefill(self.params, {"tokens": padded})
         self.cache = self._insert(self.cache, pcache,
                                   jnp.asarray(req.slot, jnp.int32))
-        ids = self._pick(logits)
+        ids = self._pick(logits, *_moe_stats(pcache))
         if h is not None:
             h.span_end("prefill.dispatch", None, None)
             h.span_begin("prefill.fetch", None)
@@ -194,7 +219,7 @@ class InferenceEngine:
             req.tokens.append(tok)
         if h is not None:
             h.event("engine.first_token", req.rid, None)
-            h.span_end("engine.prefill", None, None)
+            h.span_end("engine.prefill", None, _moe_attrs(ids, 1) or None)
 
     def do_decode_step(self) -> List[_Request]:
         """One continuous-batching decode step (offload-pool work).
@@ -226,7 +251,7 @@ class InferenceEngine:
         logits, self.cache = self._decode(self.params, self.cache,
                                           jnp.asarray(toks),
                                           jnp.asarray(pos))
-        ids = self._pick(logits)
+        ids = self._pick(logits, *_moe_stats(self.cache))
         if h is not None:
             h.span_end("decode.dispatch", None, None)
             h.span_begin("decode.device_wait", None)
@@ -265,7 +290,8 @@ class InferenceEngine:
         if h is not None:
             h.span_end("decode.commit", None, None)
             h.span_end("engine.decode_step", None,
-                       self._step_attrs(active, pending))
+                       dict(self._step_attrs(active, pending),
+                            **_moe_attrs(ids, B)))
         return finished
 
     def _step_attrs(self, active: Dict[int, _Request],

@@ -131,3 +131,47 @@ def test_qwen2_served_step_compiles_for_v5e(kind, qwen, one_chip):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 0 < used < HBM_BYTES
+
+
+_DS = dict(max_batch=64, max_len=1024, prefill_bucket=512)   # the cell's
+
+
+@pytest.fixture(scope="module")
+def deepseek(one_chip):
+    model = Model(get_config("deepseek-v2-lite").with_(remat=False))
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    cache = model.init_cache(_DS["max_batch"], _DS["max_len"], abstract=True)
+    return model, params, cache
+
+
+@pytest.mark.parametrize("kind", ("prefill", "decode_step"))
+def test_deepseek_v2_lite_served_step_compiles_for_v5e(kind, deepseek,
+                                                       one_chip):
+    """The dropless expert layer's grouped products lower to the TPU's
+    ragged-dot kernel, and the decode step copies no stack of the expert
+    layers' cache: each group's scan streams its own leaves (copies of one
+    layer's rotary keys, as minicpm3-4b's decode makes, remain), and the
+    experts' stacks are read in place."""
+    model, params, cache = deepseek
+    B = _DS["max_batch"]
+    if kind == "prefill":
+        fn, args = model.prefill, (params,
+                                   {"tokens": sd((1, _DS["prefill_bucket"]),
+                                                 i32)})
+    else:
+        fn, args = model.decode_step, (params, cache, sd((B, 1), i32),
+                                       sd((B,), i32))
+    compiled = _compile(fn, args, one_chip)
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 0 < used < HBM_BYTES
+    text = compiled.as_text()
+    assert "ragged-dot" in text and "tpu_custom_call" in text
+    if kind == "decode_step":
+        leaves = {"bf16[" + ",".join(map(str, a.shape)) + "]"
+                  for k, a in cache.items() if a.shape[0] > 1}
+        copies = [line for line in text.splitlines()
+                  if " copy(" in line
+                  and line.split(" = ", 1)[-1].split("{", 1)[0] in leaves]
+        assert copies == []
