@@ -121,10 +121,13 @@ def cache_shardings(ctx: ShardCtx, cfg: ModelConfig,
                 names = (None, "batch", None, "tp", None)
             else:
                 names = (None, "batch", "kv_seq", None, None)
-        elif name == "latent" and nd == 4:            # MLA (L,B,S,r)
+        elif name in ("k", "v") and nd == 4:          # (L,B,S,Hkv*D)
+            if heads_divide:                          # whole heads per shard
+                names = (None, "batch", None, "tp")
+            else:
+                names = (None, "batch", "kv_seq", None)
+        elif name in ("latent", "k_rope") and nd == 4:   # MLA (L,B,S,r|dr)
             names = (None, "batch", "kv_seq", None)
-        elif name == "k_rope" and nd == 5:            # (L,B,S,1,dr)
-            names = (None, "batch", "kv_seq", None, None)
         elif name == "wkv" and nd == 5:               # rwkv (L,B,H,D,D)
             names = (None, "batch", "tp", None, None)
         elif name == "h" and nd == 3:                 # lru (Lr,B,W)

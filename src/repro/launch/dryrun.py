@@ -77,7 +77,6 @@ def _f16_shadow(cfg, settings):
 # §Perf hillclimb variants: config/settings overrides lowered side by side
 # with the baseline (results keyed "<arch>|<shape>|<mesh>#<variant>")
 VARIANTS: Dict[str, Dict[str, Any]] = {
-    "carry_cache": {"cfg": {"decode_carry_cache": True}},
     "attn_chunk512": {"cfg": {"attn_chunk_threshold": 512}},
     "attn_chunk1024": {"cfg": {"attn_chunk_threshold": 1024}},
     "compress_pod": {"settings": {"compress_grads": True}},

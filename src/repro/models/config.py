@@ -89,8 +89,6 @@ class ModelConfig:
 
     # ---- perf variants (hillclimb levers; see EXPERIMENTS.md §Perf) --------
     attn_chunk_threshold: int = 4096   # q length above which attention chunks
-    decode_carry_cache: bool = False   # thread decode cache through the scan
-                                       # carry (in-place) instead of xs->ys
     attn_online: bool = False          # online-softmax (flash) attention at
                                        # the XLA level: no S x T score tensor
                                        # ever reaches HBM

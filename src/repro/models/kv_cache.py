@@ -3,17 +3,24 @@
 All caches are plain pytrees (dicts of arrays) with a leading layer dim that
 aligns with scan-over-layers.  Shapes:
 
-  full    : k/v (L, B, Smax, Hkv, D), pos-indexed scatter insert
-  mla     : latent (L, B, Smax, kv_lora), k_rope (L, B, Smax, 1, dr)
+  full    : k/v (L, B, Smax, Hkv*D)
+  mla     : latent (L, B, Smax, kv_lora), k_rope (L, B, Smax, dr)
   window  : k/v (L, B, W, Hkv, D) ring buffer + slot positions (L, B, W)
   rwkv    : wkv state (L, B, H, Dk, Dv) fp32 + token-shift prevs (L, B, d)
   lru     : h (L, B, lru_width) fp32 + conv tail (L, B, cw-1, lru_width)
-  encdec  : decoder self full-cache + precomputed cross k/v
+  encdec  : decoder self cache k/v (L, B, Smax, Hkv, D) + precomputed
+            cross k/v
+
+The decoder LMs' leaves (full, mla) are (L, B, Smax, C): one position's
+entries are one row of C, the minor dimension, so attention reads a
+layer's (Smax, C) rows as they are stored (on a TPU, in (8, 128) tiles
+with no relayout), and a decode step writes each slot's new row in place
+(:func:`write_entries`) once every layer has computed it.
 
 A model with leading dense layers (``ModelConfig.n_dense_layers``) keeps
 their cache in leaves of their own, named with the ``dense_`` prefix
 (``dense_latent``, ``dense_k_rope``), beside the other layers' stack: each
-group's decode scan streams its own leaves, and no step concatenates them.
+group's decode scan reads its own leaves, and no step concatenates them.
 
 An expert model's cache also carries ``moe_stats`` (1, B, 2) int32: what
 the last prefill or decode step's expert layers counted for each row
@@ -34,6 +41,7 @@ from .config import ModelConfig
 
 
 DENSE = "dense_"      # prefix of the leading dense layers' cache leaves
+LANES = 128           # a TPU vector register's minor dimension
 
 
 def _maybe(shape, dtype, abstract: bool):
@@ -51,7 +59,7 @@ def _maybe_full(shape, value, dtype, abstract: bool):
 # ------------------------------------------------------------------- full
 def init_full_cache(cfg: ModelConfig, n_layers: int, batch: int, max_len: int,
                     abstract: bool = False) -> Dict[str, Any]:
-    shape = (n_layers, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
+    shape = (n_layers, batch, max_len, cfg.n_kv_heads * cfg.head_dim)
     return {"k": _maybe(shape, cfg.cdtype, abstract),
             "v": _maybe(shape, cfg.cdtype, abstract)}
 
@@ -59,7 +67,8 @@ def init_full_cache(cfg: ModelConfig, n_layers: int, batch: int, max_len: int,
 def update_full_cache(ck: jax.Array, cv: jax.Array, k: jax.Array,
                       v: jax.Array, pos: jax.Array
                       ) -> Tuple[jax.Array, jax.Array]:
-    """Insert one token per sequence. ck/cv: (B,Smax,H,D); k/v: (B,1,H,D)."""
+    """Insert one token per sequence into the encoder-decoder's self cache.
+    ck/cv: (B,Smax,H,D); k/v: (B,1,H,D)."""
     b = jnp.arange(ck.shape[0])
     ck = ck.at[b, pos].set(k[:, 0].astype(ck.dtype))
     cv = cv.at[b, pos].set(v[:, 0].astype(cv.dtype))
@@ -73,18 +82,46 @@ def init_mla_cache(cfg: ModelConfig, n_layers: int, batch: int,
     return {
         "latent": _maybe((L, batch, max_len, cfg.kv_lora_rank), cfg.cdtype,
                          abstract),
-        "k_rope": _maybe((L, batch, max_len, 1, cfg.qk_rope_dim), cfg.cdtype,
+        "k_rope": _maybe((L, batch, max_len, cfg.qk_rope_dim), cfg.cdtype,
                          abstract),
     }
 
 
-def update_mla_cache(clat: jax.Array, crope: jax.Array, latent: jax.Array,
-                     k_rope: jax.Array, pos: jax.Array
-                     ) -> Tuple[jax.Array, jax.Array]:
-    b = jnp.arange(clat.shape[0])
-    clat = clat.at[b, pos].set(latent[:, 0].astype(clat.dtype))
-    crope = crope.at[b, pos].set(k_rope[:, 0].astype(crope.dtype))
-    return clat, crope
+# -------------------------------------------------- decoder LM entries
+def cache_entries(cfg: ModelConfig, kv: Tuple[jax.Array, jax.Array]
+                  ) -> Dict[str, jax.Array]:
+    """A block's keys and values as the cache stores them, by leaf name:
+    GQA's k/v (..., S, Hkv, D) as (..., S, Hkv*D); MLA's latent
+    (..., S, r) as it is, and its rotary key (..., S, 1, dr) as
+    (..., S, dr)."""
+    a, b = kv
+    if cfg.use_mla:
+        return {"latent": a, "k_rope": b[..., 0, :]}
+    return {"k": a.reshape(a.shape[:-2] + (-1,)),
+            "v": b.reshape(b.shape[:-2] + (-1,))}
+
+
+def write_entries(leaf: jax.Array, new: jax.Array, pos: jax.Array
+                  ) -> jax.Array:
+    """Write each slot's new entries into a stored leaf: ``leaf``
+    (L, B, Smax, C), ``new`` (L, B, C) every layer's row for the token at
+    ``pos`` (B,).  On a donated leaf both ways below run in place.
+
+    Rows of a multiple of ``LANES`` entries are stored row after row, and
+    one scatter of L x B rows writes them.  A TPU stores narrower rows
+    (MLA's rotary key) with the positions minor, where a scatter would
+    relay the whole leaf out and back; there one update per slot writes
+    its L x C entries where they lie.
+    """
+    L, B = leaf.shape[:2]
+    new = new.astype(leaf.dtype)
+    if leaf.shape[-1] % LANES == 0:
+        return leaf.at[jnp.arange(L)[:, None], jnp.arange(B)[None],
+                       pos[None]].set(new, unique_indices=True)
+    for b in range(B):
+        leaf = jax.lax.dynamic_update_slice(leaf, new[:, b, None, None],
+                                            (0, b, pos[b], 0))
+    return leaf
 
 
 # ------------------------------------------------------------------ window
@@ -140,9 +177,11 @@ def init_hybrid_cache(cfg: ModelConfig, batch: int,
 def init_encdec_cache(cfg: ModelConfig, batch: int, max_len: int,
                       abstract: bool = False) -> Dict[str, Any]:
     Ld = cfg.n_layers
+    self_shape = (Ld, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
     cross_shape = (Ld, batch, cfg.cross_len, cfg.n_kv_heads, cfg.head_dim)
     return {
-        "self": init_full_cache(cfg, Ld, batch, max_len, abstract),
+        "self": {"k": _maybe(self_shape, cfg.cdtype, abstract),
+                 "v": _maybe(self_shape, cfg.cdtype, abstract)},
         "cross_k": _maybe(cross_shape, cfg.cdtype, abstract),
         "cross_v": _maybe(cross_shape, cfg.cdtype, abstract),
     }

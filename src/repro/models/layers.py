@@ -343,6 +343,60 @@ def gqa_attend(q: jax.Array, k: jax.Array, v: jax.Array,
     return shard_hint(out, "batch", "seq", None)
 
 
+def _decode_probs(s: jax.Array, s_new: jax.Array, pos: jax.Array
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """Softmax over a slot's stored positions below its own and the new
+    token's own key.  s: (B, ..., T) float32 scores against the stored
+    rows, s_new: (B, ..., 1) against the new key, pos: (B,).  Returns the
+    probabilities (B, ..., T) and (B, ..., 1)."""
+    T = s.shape[-1]
+    valid = jnp.arange(T, dtype=jnp.int32)[None] < pos[:, None]      # (B,T)
+    valid = valid.reshape(valid.shape[:1] + (1,) * (s.ndim - 2) + (T,))
+    s = jnp.where(valid, s, NEG_INF)
+    m = jnp.maximum(s.max(-1, keepdims=True), s_new)
+    e, e_new = jnp.exp(s - m), jnp.exp(s_new - m)
+    total = e.sum(-1, keepdims=True) + e_new
+    return e / total, e_new / total
+
+
+def gqa_decode_attend(q: jax.Array, ck: jax.Array, cv: jax.Array,
+                      k_new: jax.Array, v_new: jax.Array, pos: jax.Array,
+                      p: dict, cfg: ModelConfig) -> jax.Array:
+    """Single-token GQA attention + output projection against one layer's
+    stored cache, which it reads and does not write.  Returns (B,1,d).
+
+    q: (B,1,H,D); ck/cv: (B,T,Hkv*D) as the cache stores them, valid below
+    each slot's ``pos`` (B,); k_new/v_new: (B,1,Hkv,D), the new token's
+    own, which join the softmax beside the stored rows.  Each kv head's
+    queries are set block-diagonally over a stored row's Hkv*D entries, so
+    both products contract or keep the row's minor dimension whole and
+    read the rows in place; that costs Hkv x the head's own FLOPs, which a
+    bandwidth-bound decode step does not feel.
+    """
+    B, _, H, D = q.shape
+    Hkv = k_new.shape[2]
+    g = H // Hkv
+    scale = D ** -0.5
+    cap = cfg.attn_logit_softcap
+    ck = shard_hint(ck, "batch", "kv_seq", None)
+    cv = shard_hint(cv, "batch", "kv_seq", None)
+    qg = q[:, 0].reshape(B, Hkv, g, D)
+    eye = jnp.eye(Hkv, dtype=q.dtype)
+    qz = jnp.einsum("bhgd,hk->bhgkd", qg, eye).reshape(B, Hkv, g, Hkv * D)
+    s = jnp.einsum("bhgc,btc->bhgt", qz, ck,
+                   preferred_element_type=jnp.float32) * scale
+    s_new = jnp.einsum("bhgd,bhd->bhg", qg, k_new[:, 0],
+                       preferred_element_type=jnp.float32)[..., None] * scale
+    probs, p_new = _decode_probs(_softcap(s, cap), _softcap(s_new, cap), pos)
+    o = jnp.einsum("bhgt,btc->bhgc", probs.astype(cv.dtype), cv,
+                   preferred_element_type=jnp.float32)
+    o = jnp.einsum("bhgkd,hk->bhgd", o.reshape(B, Hkv, g, Hkv, D),
+                   eye.astype(jnp.float32))
+    o = o + p_new * v_new[:, 0, :, None, :].astype(jnp.float32)
+    out = linear(o.astype(q.dtype).reshape(B, 1, H * D), p["wo"])
+    return shard_hint(out, "batch", "seq", None)
+
+
 def gqa_attention_block(x: jax.Array, p: dict, cfg: ModelConfig,
                         positions: jax.Array, mask: Optional[jax.Array],
                         ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
@@ -466,6 +520,48 @@ def mla_attend(qq: jax.Array, latent: jax.Array, k_rope: jax.Array,
         out = jnp.moveaxis(outs, 0, 1).reshape(B, S, H, dv)
 
     out = linear(out.reshape(B, S, H * dv), p["wo"])
+    return shard_hint(out, "batch", "seq", None)
+
+
+def mla_decode_attend(qq: jax.Array, clat: jax.Array, crope: jax.Array,
+                      lat_new: jax.Array, rope_new: jax.Array,
+                      pos: jax.Array, p: dict, cfg: ModelConfig) -> jax.Array:
+    """Single-token latent-space MLA attention (as :func:`mla_attend`)
+    against one layer's stored cache, which it reads and does not write.
+    Returns (B,1,d).
+
+    qq: (B,1,H,nope+rope); clat (B,T,r) and crope (B,T,dr) as the cache
+    stores them, valid below each slot's ``pos`` (B,); lat_new (B,1,r) and
+    rope_new (B,1,1,dr), the new token's own, which join the softmax
+    beside the stored rows.
+    """
+    B = qq.shape[0]
+    H, dn, dr, dv = (cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim,
+                     cfg.v_head_dim)
+    r = cfg.kv_lora_rank
+    scale = (dn + dr) ** -0.5 * cfg.yarn_attn_scale
+    clat = shard_hint(clat, "batch", "kv_seq", None)
+    crope = shard_hint(crope, "batch", "kv_seq", None)
+
+    w = p["wkv_b"].reshape(r, H, dn + dv)
+    w_uk, w_uv = w[..., :dn], w[..., dn:]
+    q_abs = jnp.einsum("bhn,rhn->bhr", qq[:, 0, :, :dn],
+                       w_uk.astype(qq.dtype))
+    q_rope = qq[:, 0, :, dn:]
+    lat1, rope1 = lat_new[:, 0], rope_new[:, 0, 0]
+    f32 = jnp.float32
+    s = (jnp.einsum("bhr,btr->bht", q_abs, clat, preferred_element_type=f32)
+         + jnp.einsum("bhp,btp->bht", q_rope, crope,
+                      preferred_element_type=f32)) * scale
+    s_new = (jnp.einsum("bhr,br->bh", q_abs, lat1, preferred_element_type=f32)
+             + jnp.einsum("bhp,bp->bh", q_rope, rope1,
+                          preferred_element_type=f32))[..., None] * scale
+    probs, p_new = _decode_probs(s, s_new, pos)
+    ctx = jnp.einsum("bht,btr->bhr", probs.astype(clat.dtype), clat,
+                     preferred_element_type=f32)
+    ctx = (ctx + p_new * lat1[:, None].astype(f32)).astype(qq.dtype)
+    out = jnp.einsum("bhr,rhv->bhv", ctx, w_uv.astype(ctx.dtype))
+    out = linear(out.reshape(B, 1, H * dv), p["wo"])
     return shard_hint(out, "batch", "seq", None)
 
 

@@ -16,10 +16,11 @@ import jax.numpy as jnp
 
 from ..distributed import shard_hint
 from .config import ModelConfig
-from .kv_cache import DENSE, update_full_cache, update_mla_cache
-from .layers import (attention_scores_mask, embed_tokens, gqa_attend,
-                     gqa_project, lm_logits, mla_attend, mla_latent,
-                     mla_project_q, moe_mlp, rms_norm, swiglu_mlp)
+from .kv_cache import DENSE, cache_entries, write_entries
+from .layers import (embed_tokens, gqa_attend, gqa_decode_attend,
+                     gqa_project, lm_logits, mla_attend, mla_decode_attend,
+                     mla_latent, mla_project_q, moe_mlp, rms_norm,
+                     swiglu_mlp)
 
 
 # ------------------------------------------------------------------ blocks
@@ -80,49 +81,32 @@ def block_decode(x: jax.Array, p: Dict[str, Any], cfg: ModelConfig,
                  cache_l: Dict[str, jax.Array], pos: jax.Array
                  ) -> Tuple[jax.Array, Dict[str, jax.Array],
                             Optional[jax.Array]]:
-    """One decoder block, single-token decode against the layer's KV cache.
+    """One decoder block, single-token decode against the layer's stored
+    cache, which it reads and does not write.
 
-    x: (B,1,d); pos: (B,) absolute position of the new token.  Returns
-    (x, the layer's new cache, expert stats or None).
+    x: (B,1,d); cache_l: the layer's leaves (B, T, C); pos: (B,) absolute
+    position of the new token.  Returns (x, the new token's entries by
+    leaf name (B, C), expert stats or None).
     """
-    B = x.shape[0]
     positions = pos[:, None]                                    # (B,1)
     with jax.named_scope("attention"):
         h = rms_norm(x, p["ln1"], cfg.norm_eps)
         if cfg.use_mla:
-            lat_new, rope_new = mla_latent(h, p["attn"], cfg, positions)
-            with jax.named_scope("cache_update"):
-                lat, ropek = update_mla_cache(cache_l["latent"],
-                                              cache_l["k_rope"], lat_new,
-                                              rope_new, pos)
-            mask = _cache_mask(pos, lat.shape[1])
+            kv = mla_latent(h, p["attn"], cfg, positions)
             qq = mla_project_q(h, p["attn"], cfg, positions)
-            attn = mla_attend(qq, lat, ropek, mask, p["attn"], cfg)
-            new_cache = {"latent": lat, "k_rope": ropek}
+            attn = mla_decode_attend(qq, cache_l["latent"],
+                                     cache_l["k_rope"], *kv, pos, p["attn"],
+                                     cfg)
         else:
-            q, k_new, v_new = gqa_project(h, p["attn"], cfg, positions)
-            with jax.named_scope("cache_update"):
-                ck, cv = update_full_cache(cache_l["k"], cache_l["v"],
-                                           k_new, v_new, pos)
-            mask = _cache_mask(pos, ck.shape[1])
-            ck_a = shard_hint(ck, "batch", "kv_seq", None, None)
-            cv_a = shard_hint(cv, "batch", "kv_seq", None, None)
-            attn = gqa_attend(q, ck_a, cv_a, mask, p["attn"], cfg)
-            new_cache = {"k": ck, "v": cv}
+            q, *kv = gqa_project(h, p["attn"], cfg, positions)
+            attn = gqa_decode_attend(q, cache_l["k"], cache_l["v"], *kv, pos,
+                                     p["attn"], cfg)
         x = x + attn
     with jax.named_scope("mlp"):
         h = rms_norm(x, p["ln2"], cfg.norm_eps)
         ff, _, stats = _mlp(h, p["mlp"], cfg, dropless=True)
-        return x + ff, new_cache, stats
-
-
-def _cache_mask(pos: jax.Array, max_len: int) -> jax.Array:
-    """(B,1,T) additive mask: valid cache slots are those <= current pos."""
-    B = pos.shape[0]
-    kpos = jnp.broadcast_to(jnp.arange(max_len, dtype=jnp.int32)[None],
-                            (B, max_len))
-    kpos = jnp.where(kpos <= pos[:, None], kpos, -1)
-    return attention_scores_mask(pos[:, None], kpos, causal=False)
+    new = {k: a[:, 0] for k, a in cache_entries(cfg, kv).items()}
+    return x + ff, new, stats
 
 
 # ------------------------------------------------------------------ model
@@ -244,10 +228,7 @@ def prefill(params: Dict[str, Any], cfg: ModelConfig,
         fn = partial(body, whole=whole)
         carry, kvs = jax.lax.scan(jax.checkpoint(fn) if cfg.remat else fn,
                                   carry, xs)
-        if cfg.use_mla:
-            cache.update(_named(group, {"latent": kvs[0], "k_rope": kvs[1]}))
-        else:
-            cache.update(_named(group, {"k": kvs[0], "v": kvs[1]}))
+        cache.update(_named(group, cache_entries(cfg, kvs)))
     x, stats = carry
     with jax.named_scope("head"):
         x = rms_norm(x, params["ln_f"], cfg.norm_eps)
@@ -264,51 +245,34 @@ def decode_step(params: Dict[str, Any], cfg: ModelConfig,
                 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """One decode step. tokens: (B,1); pos: (B,). Returns (logits(B,V), cache).
 
-    Each layer group scans its own weights and its own cache leaves, so the
-    leading dense layers and the rest each stream their cache xs -> ys.
+    Each layer group scans its own weights, and each layer reads its slice
+    of the group's stored leaves in place: nothing is written while the
+    layers run.  The scan emits only the new token's entries, (L, B, C) per
+    leaf, which ``write_entries`` writes at each slot's position
+    afterwards, so a donated cache is updated in place and no layer's slab
+    is copied.
     """
     x = embed_tokens(tokens, params["embed"], scale=cfg.embed_scale)
     x = x.astype(cfg.cdtype)
     carry, new_cache = (x, _stats0(cfg, x.shape[0])), {}
 
-    # §Perf variant (decode_carry_cache): thread the whole cache through
-    # the scan *carry* so XLA updates it in place (one buffer), instead of
-    # streaming it as xs -> stacked ys (two buffers: 2x cache HBM at
-    # 405B/32k).
-    def body_carry(carry, xs, whole=None):
-        h, st, c = carry
-        p_l, i = xs
-        cache_l = jax.tree.map(
-            lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
-            c)
-        h2, new_cache_l, s = block_decode(h, _layer(p_l, whole, i), cfg,
-                                          cache_l, pos)
-        c = jax.tree.map(
-            lambda a, n: jax.lax.dynamic_update_index_in_dim(
-                a, n.astype(a.dtype), i, 0), c, new_cache_l)
-        return (h2, _add(st, s), c), None
-
-    def body(carry, xs, whole=None):
+    def body(carry, xs, kv, whole=None):
         h, st = carry
-        p_l, cache_l, *i = xs
-        h2, new_cache_l, s = block_decode(h, _layer(p_l, whole, *i), cfg,
-                                          cache_l, pos)
-        return (h2, _add(st, s)), new_cache_l
+        p_l, i = xs
+        kv_l = {k: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
+                for k, a in kv.items()}
+        h2, new_l, s = block_decode(h, _layer(p_l, whole, i), cfg, kv_l, pos)
+        return (h2, _add(st, s)), new_l
 
     for group in _groups(params):
         kv = _cache_of(cache, group)
         p_xs, whole = _served(params[group])
         L = jax.tree.leaves(kv)[0].shape[0]
-        if cfg.decode_carry_cache:
-            (h, st, kv), _ = jax.lax.scan(
-                partial(body_carry, whole=whole), carry + (kv,),
-                (p_xs, jnp.arange(L, dtype=jnp.int32)))
-            carry = (h, st)
-        else:
-            xs = (p_xs, kv) if whole is None \
-                else (p_xs, kv, jnp.arange(L, dtype=jnp.int32))
-            carry, kv = jax.lax.scan(partial(body, whole=whole), carry, xs)
-        new_cache.update(_named(group, kv))
+        carry, new = jax.lax.scan(partial(body, kv=kv, whole=whole), carry,
+                                  (p_xs, jnp.arange(L, dtype=jnp.int32)))
+        with jax.named_scope("cache_update"):
+            new_cache.update(_named(group, {
+                k: write_entries(kv[k], new[k], pos) for k in kv}))
     x, stats = carry
     with jax.named_scope("head"):
         x = rms_norm(x, params["ln_f"], cfg.norm_eps)

@@ -22,6 +22,10 @@ dispatched), ``decode.device_wait`` (waits for both), ``decode.fetch``
 (copies B int32 ids), ``decode.sample`` (reads the id of each active slot)
 and ``decode.commit``, and ``engine.lock_wait`` for each wait on the lock.
 
+The engine's cache is donated to the programs that update it, the insert
+and the decode step (``_decode_step``, which runs whatever ``_decode`` is),
+so each writes its new entries in place and no step holds a second cache.
+
 Greedy tokens are picked on the device by ``_greedy_pick``, a program of
 its own applied to whatever ``_prefill``/``_decode`` return, so the host
 copies one id per slot and not a row of ``vocab`` logits.  For an expert
@@ -122,26 +126,36 @@ class InferenceEngine:
         self.generated = 0
 
         # --- jitted device functions -------------------------------------
+        # ``_decode`` is the step as a plain function of its arguments; the
+        # served program, ``_decode_step``, calls whatever ``_decode`` is
+        # when it is first traced, on a donated cache, so a wrapper set on
+        # ``_decode`` after construction is what is served
         self._prefill = jax.jit(model.prefill)
         self._decode = jax.jit(model.decode_step)
-        self._insert = jax.jit(self._insert_impl)
+        self._step = jax.jit(self._decode_step, donate_argnums=1)
+        self._insert = jax.jit(self._insert_impl, donate_argnums=0)
         self._pick = jax.jit(_greedy_pick)
 
     # ------------------------------------------------------------ plumbing
+    def _decode_step(self, params: Any, cache: Any, toks: jax.Array,
+                     pos: jax.Array) -> Tuple[jax.Array, Any]:
+        """The served decode step: ``_decode`` traced into the program
+        that donates the engine's cache."""
+        return self._decode(params, cache, toks, pos)
+
     @staticmethod
     def _insert_impl(cache: Any, pcache: Any, slot: jax.Array) -> Any:
-        """Copy a prefill cache (batch=1) into one slot of the engine cache.
+        """Copy a prefill cache (batch=1) into one slot of the engine cache,
+        in place where the cache is donated.
 
         Leaves are (L, B, ...) with the prefill leaf (L, 1, ...); when the
         prefill leaf is shorter along the sequence dim (full caches), it is
         placed at positions [0, P).  Recurrent-state leaves match exactly.
         """
         def ins(big, small):
-            row = small[:, 0].astype(big.dtype)        # (L, ...)
-            if row.shape != big.shape[:1] + big.shape[2:]:
-                row = jax.lax.dynamic_update_slice_in_dim(
-                    big[:, slot], row, 0, axis=1)
-            return jax.lax.dynamic_update_index_in_dim(big, row, slot, axis=1)
+            start = (0, slot) + (0,) * (big.ndim - 2)
+            return jax.lax.dynamic_update_slice(big, small.astype(big.dtype),
+                                                start)
         with jax.named_scope("cache_update"):
             return jax.tree.map(ins, cache, pcache)
 
@@ -248,9 +262,8 @@ class InferenceEngine:
         if h is not None:
             h.span_end("decode.prepare", None, None)
             h.span_begin("decode.dispatch", None)
-        logits, self.cache = self._decode(self.params, self.cache,
-                                          jnp.asarray(toks),
-                                          jnp.asarray(pos))
+        logits, self.cache = self._step(self.params, self.cache,
+                                        jnp.asarray(toks), jnp.asarray(pos))
         ids = self._pick(logits, *_moe_stats(self.cache))
         if h is not None:
             h.span_end("decode.dispatch", None, None)

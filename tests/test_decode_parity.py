@@ -81,6 +81,54 @@ def test_decode_matches_prefill(arch):
                                err_msg=f"{arch}: decode != prefill")
 
 
+@pytest.mark.parametrize("arch", ["qwen2-0.5b", "minicpm3-4b",
+                                  "deepseek-v2-lite"])
+def test_decode_steps_match_prefill(arch):
+    """Three decode steps after a prefill, each writing its token's entries
+    into the cache after its layers ran, reproduce the last-token logits of
+    full prefills of S+1, S+2 and S+3 tokens, the two slots at different
+    positions."""
+    cfg = _fp32(get_smoke_config(arch))
+    model = Model(cfg)
+    rng = jax.random.PRNGKey(7)
+    params = model.init(rng)
+    full = _inputs(model, rng, S + 3)
+    _, cache = jax.jit(model.prefill)(params, {"tokens": full["tokens"][:, :S]})
+    cache = _pad_seq_caches(cfg, cache, S + 8)
+    # slot 1 lags one token behind slot 0: its first step re-decodes the
+    # prompt's last token over the entries of the S - 1 before it
+    lag = np.array([0, 1])
+    step = jax.jit(model.decode_step)
+    for i in range(3):
+        n = S + i - lag                                   # tokens before
+        tok = full["tokens"][jnp.arange(B), n][:, None]
+        logits, cache = step(params, cache, tok, jnp.asarray(n, jnp.int32))
+        for b in range(B):
+            ref, _ = jax.jit(model.prefill)(
+                params, {"tokens": full["tokens"][b:b + 1, :n[b] + 1]})
+            np.testing.assert_allclose(
+                np.asarray(logits[b]), np.asarray(ref[0]), atol=2e-3,
+                rtol=2e-3, err_msg=f"{arch}: step {i}, slot {b}")
+
+
+@pytest.mark.parametrize("width", [128, 24])
+def test_write_entries_writes_each_slot_row(width):
+    """Both ways of writing a step's entries (one scatter of lane-wide rows,
+    one update per slot of narrower ones) set row ``pos[b]`` of every
+    layer and slot and leave every other entry as it was."""
+    from repro.models.kv_cache import write_entries
+    L, nb, T = 3, 4, 10
+    rng = np.random.default_rng(width)
+    leaf = rng.standard_normal((L, nb, T, width)).astype(np.float32)
+    new = rng.standard_normal((L, nb, width)).astype(np.float32)
+    pos = np.array([0, 9, 4, 4], np.int32)
+    want = leaf.copy()
+    want[:, np.arange(nb), pos] = new
+    got = jax.jit(write_entries)(jnp.asarray(leaf), jnp.asarray(new),
+                                 jnp.asarray(pos))
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
 @pytest.mark.parametrize("arch", ["rwkv6-3b", "recurrentgemma-9b"])
 def test_long_context_state_is_bounded(arch):
     """Sub-quadratic archs: decode-state byte size is independent of the

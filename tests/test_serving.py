@@ -104,12 +104,15 @@ def test_greedy_pick_matches_np_argmax(rows):
     assert int(picks[0]) == 4
 
 
-@pytest.mark.parametrize("arch", ["qwen2-0.5b", "minicpm3-4b"])
+@pytest.mark.parametrize("arch", ["qwen2-0.5b", "minicpm3-4b",
+                                  "deepseek-v2-lite"])
 def test_served_tokens_equal_host_argmax_of_decode_logits(arch):
     """Tokens picked on the device equal a host argmax over ``_decode``'s
     logits (``chip_smoke.greedy_reference``: each request alone in slot 0),
     with two of four slots active and, once the shorter request ends, three
-    slots padded."""
+    slots padded.  The engine serves through its donating programs (the
+    insert and ``_decode_step``), the reference through the plain
+    ``_decode`` on a cache of its own."""
     model, params = _tiny_model(arch)
     # At init the blocks barely move the residual, and qwen2's tied head
     # then echoes the last (pad) token; larger weights give each request
@@ -132,6 +135,58 @@ def test_served_tokens_equal_host_argmax_of_decode_logits(arch):
     assert served[0][:3] != served[1]
     assert served == [chip_smoke.greedy_reference(eng, p, n)[0]
                       for p, n in zip(prompts, lengths)]
+
+
+def _forced_token(decode):
+    """A step whose logits pick token 7 in every slot."""
+    def step(params, cache, toks, pos):
+        logits, cache = decode(params, cache, toks, pos)
+        return jnp.zeros_like(logits).at[:, 7].set(1.0), cache
+    return step
+
+
+def _unchanged_state(decode):
+    """A step that returns the cache it was given (no new entries)."""
+    return lambda params, cache, toks, pos: (
+        decode(params, cache, toks, pos)[0], cache)
+
+
+@pytest.mark.parametrize("wrapper", [_forced_token, _unchanged_state])
+def test_wrapper_set_on_decode_decides_what_is_served(wrapper):
+    """A wrapper set on ``engine._decode`` after construction, as the chip
+    benchmark plants its faults, is what the donating decode program runs:
+    forced tokens are served, and a step that hands back the cache it was
+    given serves the tokens of a cache that never moves past the insert,
+    on live buffers."""
+    model, params = _tiny_model()
+    P, n_new = 16, 5
+    scfg = ServeConfig(max_batch=2, max_len=64, prefill_bucket=P,
+                       max_new_tokens=n_new)
+    prompt = (np.arange(8, dtype=np.int32) * 7 + 3) % model.cfg.vocab_size
+    eng = InferenceEngine(model, params, scfg)
+    plain = eng._decode
+    eng._decode = wrapper(plain)
+    done = eng.submit(prompt)
+    eng.do_prefill(eng.admit_one()[0])
+    inserted = jax.tree.map(np.asarray, eng.cache)
+    while not done.done:
+        eng.do_decode_step()
+    served = done.wait(timeout=5)
+    if wrapper is _forced_token:
+        assert served[1:] == [7] * (n_new - 1)
+    else:
+        want = [served[0]]
+        for i in range(n_new - 1):
+            toks = np.zeros((2, 1), np.int32)
+            toks[1, 0] = want[-1]
+            pos = np.array([0, P + i], np.int32)
+            logits, _ = plain(params, inserted, jnp.asarray(toks),
+                              jnp.asarray(pos))
+            want.append(int(np.argmax(np.asarray(logits)[1])))
+        assert served == want
+        for a, b in zip(jax.tree.leaves(eng.cache),
+                        jax.tree.leaves(inserted)):
+            np.testing.assert_array_equal(np.asarray(a), b)
 
 
 @pytest.mark.parametrize("backend", BACKENDS)

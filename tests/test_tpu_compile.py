@@ -8,6 +8,8 @@ topology is described inside a fixture, never at import, so that under
 several pytest workers only the worker given this file loads the TPU
 library.  Nothing runs; these tests say nothing about results or speed.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -19,7 +21,7 @@ from repro.kernels.flash_attention.kernel import flash_attention_fwd
 from repro.kernels.rglru_scan.kernel import rglru_scan_fwd
 from repro.kernels.rwkv6_scan.kernel import wkv6_fwd
 from repro.models import Model
-from repro.serving import InferenceEngine
+from repro.serving import InferenceEngine, ServeConfig
 from repro.serving.engine import _greedy_pick
 from test_chip_smoke import chip_smoke
 
@@ -55,11 +57,15 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile(fn, args, one_chip):
-    placed = jax.tree.map(
+def _place(args, one_chip):
+    return jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
         args)
-    return jax.jit(fn).lower(*placed).compile()
+
+
+def _compile(fn, args, one_chip, donate=()):
+    return jax.jit(fn, donate_argnums=donate).lower(
+        *_place(args, one_chip)).compile()
 
 
 sd = jax.ShapeDtypeStruct
@@ -148,12 +154,14 @@ def deepseek(one_chip):
 def test_deepseek_v2_lite_served_step_compiles_for_v5e(kind, deepseek,
                                                        one_chip):
     """The dropless expert layer's grouped products lower to the TPU's
-    ragged-dot kernel, and the decode step copies no stack of the expert
-    layers' cache: each group's scan streams its own leaves (copies of one
-    layer's rotary keys, as minicpm3-4b's decode makes, remain), and the
-    experts' stacks are read in place."""
+    ragged-dot kernel, and the decode step, on a donated cache as the
+    engine serves it, copies no stack of the expert layers' cache: each
+    group's layers read their own leaves in place, and the experts' stacks
+    are read in place.  (Without donation a step must copy the cache it
+    returns.)"""
     model, params, cache = deepseek
     B = _DS["max_batch"]
+    donate = ()
     if kind == "prefill":
         fn, args = model.prefill, (params,
                                    {"tokens": sd((1, _DS["prefill_bucket"]),
@@ -161,7 +169,8 @@ def test_deepseek_v2_lite_served_step_compiles_for_v5e(kind, deepseek,
     else:
         fn, args = model.decode_step, (params, cache, sd((B, 1), i32),
                                        sd((B,), i32))
-    compiled = _compile(fn, args, one_chip)
+        donate = (1,)
+    compiled = _compile(fn, args, one_chip, donate)
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
@@ -175,3 +184,62 @@ def test_deepseek_v2_lite_served_step_compiles_for_v5e(kind, deepseek,
                   if " copy(" in line
                   and line.split(" = ", 1)[-1].split("{", 1)[0] in leaves]
         assert copies == []
+
+
+# the cells' engines: (max_batch, max_len, prefill_bucket)
+ENGINES = {"qwen2-0.5b": (64, 1024, 256), "minicpm3-4b": (32, 1536, 512),
+           "deepseek-v2-lite": (64, 1024, 512)}
+
+
+def _copied_dims(line):
+    """The dimensions a ``copy`` or ``copy-start`` writes, or None for
+    another instruction or for a copy-start that only moves an array
+    between memory spaces in the layout it has (a prefetch, which a fusion
+    then reads from the core's own memory)."""
+    m = re.search(r"= (\(?)(.*?) copy(-start)?\(", line)
+    if m is None:
+        return None
+    arrays = re.findall(r"\w+\[([\d,]*)\](\{[^}]*\})?", m.group(2))
+    if m.group(3):
+        (dims, dst), (_, src) = arrays[:2]
+        if re.sub(r"S\(\d\)", "", dst) == re.sub(r"S\(\d\)", "", src):
+            return None
+    return tuple(int(d) for d in arrays[0][0].split(",") if d)
+
+
+@pytest.mark.parametrize("program", ("decode", "insert"))
+@pytest.mark.parametrize("arch", sorted(ENGINES))
+def test_engine_moves_the_cache_in_place_on_v5e(arch, program, one_chip):
+    """The engine's donated decode step and insert at a cell's size update
+    the cache where it lies: the whole cache is aliased from input to
+    output, the temporaries stay below one layer's slab of it, and no copy
+    has the shape of a layer's slab or of a whole leaf, save a prefetch
+    into the core's memory in the layout the leaf has."""
+    B, T, P = ENGINES[arch]
+    model = Model(get_config(arch).with_(remat=False))
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    engine = InferenceEngine(model, params, ServeConfig(
+        max_batch=1, max_len=8, prefill_bucket=4))       # programs only
+    cache = model.init_cache(B, T, abstract=True)
+    if program == "decode":
+        fn, args = engine._step, (params, cache, sd((B, 1), i32),
+                                  sd((B,), i32))
+    else:
+        pcache = jax.eval_shape(model.prefill, params,
+                                {"tokens": sd((1, P), i32)})[1]
+        fn, args = engine._insert, (cache, pcache, sd((), i32))
+    compiled = fn.lower(*_place(args, one_chip)).compile()
+    mem = compiled.memory_analysis()
+    # the leaves of entries; the expert counts, a few hundred bytes that
+    # each step writes anew, may be aliased too, in a device tile
+    leaves = [a for k, a in cache.items() if k != "moe_stats"]
+    nbytes = [a.size * a.dtype.itemsize for a in leaves]
+    assert 0 <= mem.alias_size_in_bytes - sum(nbytes) < 4096
+    slab = max(n // a.shape[0] for a, n in zip(leaves, nbytes))
+    assert mem.temp_size_in_bytes < slab
+    shapes = set()
+    for a in leaves:
+        shapes |= {a.shape, a.shape[1:], (1,) + a.shape[1:]}
+    copies = [line for line in compiled.as_text().splitlines()
+              if _copied_dims(line) in shapes]
+    assert copies == []
